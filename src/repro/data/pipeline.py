@@ -14,6 +14,8 @@ from typing import Iterator, Optional
 
 import numpy as np
 
+from repro.obs.record import profiler_span
+
 
 @dataclass
 class SyntheticTokens:
@@ -65,7 +67,10 @@ class SyntheticTokens:
 
 
 class Prefetcher:
-    """Background-thread double buffering over any batch iterator."""
+    """Background-thread double buffering over any batch iterator.
+
+    Under a profiler session each ``next()`` is the span ``data.wait``,
+    with the queue's size when the call began as the stat ``depth``."""
 
     def __init__(self, it: Iterator, depth: int = 2):
         self._it = it
@@ -89,7 +94,8 @@ class Prefetcher:
         return self
 
     def __next__(self):
-        item = self._q.get()
+        with profiler_span("data.wait", depth=self._q.qsize()):
+            item = self._q.get()
         if item is None:
             if self._err is not None:
                 raise self._err

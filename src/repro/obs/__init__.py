@@ -13,6 +13,9 @@ single parity percentage.  Three pieces:
   simulator's :class:`~repro.core.graph.DataflowGraph` /
   :class:`~repro.serve.policy.StepPlan` use, so a real run produces a
   timeline in the same schema as :class:`~repro.core.simulator.SimResult`.
+  While a JAX profiler session runs, the same spans (and the executors'
+  own :func:`~repro.obs.record.profiler_span` phases) also land in the
+  profiler's trace, on the device events' clock.
 
 * :mod:`repro.obs.overlay` — one Perfetto/Chrome JSON with aligned
   ``sim:`` and ``real:`` tracks per device, pricing provenance and byte

@@ -41,6 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.models.build import Model
+from repro.obs.record import profiler_span
 from repro.serve import paged
 from repro.serve.policy import ServeConfig, ServeScheduler, StepPlan
 
@@ -101,11 +102,13 @@ class ServeEngine:
         self.pool = self._replicated(paged.init_pool(self.cfg, self.serve_cfg))
 
         scfg = self.serve_cfg
-        self._decode = jax.jit(
-            lambda p, pool, t, ln, tb: paged.decode_batch(
-                p, pool, t, ln, tb, self.cfg, scfg
-            )
-        )
+
+        def serve_decode(p, pool, t, ln, tb):
+            return paged.decode_batch(p, pool, t, ln, tb, self.cfg, scfg)
+
+        # the programs' names (``jit_serve_decode``, ``jit_serve_prefill``)
+        # are what a profiler trace tells them apart by
+        self._decode = jax.jit(serve_decode)
         self._prefill_cache: dict[int, Callable] = {}
         # duration source only — scheduling time is sched.clock (see module
         # docstring); injectable for deterministic tests.  Telemetry goes
@@ -182,36 +185,55 @@ class ServeEngine:
             scfg = self.serve_cfg
             scratch = self.sched.scratch_block
 
-            def fn(p, pool, toks, start, width, row):
+            def serve_prefill(p, pool, toks, start, width, row):
                 return paged.prefill_chunk(
                     p, pool, toks, start, width, row, scratch, self.cfg, scfg
                 )
 
-            self._prefill_cache[bucket] = jax.jit(fn)
+            self._prefill_cache[bucket] = jax.jit(serve_prefill)
         return self._prefill_cache[bucket]
 
     # -- one engine step -------------------------------------------------------
 
     def step(self) -> bool:
-        """Execute one scheduler step; False if nothing can progress."""
+        """Execute one scheduler step; False if nothing can progress.
+
+        Under a profiler session the step is the span ``serve.step``, and
+        each phase of it a span of its own (docs/observability.md)."""
+        sched = self.sched
+        if not sched.outstanding():
+            return False
+        with profiler_span(
+            "serve.step", index=sched.step_index, queued=len(sched.queue),
+            live=sum(r is not None for r in self.slot_req),
+            free_blocks=sched.allocator.num_free,
+        ):
+            with profiler_span("serve.plan"):
+                plan = self._plan()
+            if plan is None:
+                return False
+            self._execute(plan)
+            return True
+
+    def _plan(self) -> Optional[StepPlan]:
         plan = self.sched.plan_step()
         if plan.empty:
             nxt = self.sched.next_arrival()
             if nxt is None:
-                return False
+                return None
             # open-loop replay: jump the clock to the next arrival instead
             # of sleeping through the gap
             self.sched.skip_to(nxt)
             plan = self.sched.plan_step()
             if plan.empty:
-                return False
-        self._execute(plan)
-        return True
+                return None
+        return plan
 
     def _execute(self, plan: StepPlan) -> None:
         rec = self._rec
         iv = rec.interval(
-            f"step{plan.index}", "host", kind="serve-step", role="step"
+            f"step{plan.index}", "host", kind="serve-step",
+            layer="serve.execute", role="step",
         )
         scratch = self.sched.scratch_block
         for rid, slot in plan.admitted:
@@ -240,16 +262,21 @@ class ServeEngine:
                     f"{'no request' if req is None else f'request {req.rid}'} "
                     f"(statically detectable as R006)"
                 )
-            toks = np.zeros((1, pf.bucket), np.int32)
-            toks[0, : pf.width] = req.prompt[pf.start : pf.start + pf.width]
-            t0 = rec.clock() if rec.enabled else 0.0
-            logits, self.pool = self._prefill_fn(pf.bucket)(
-                self.params, self.pool, jnp.asarray(toks),
-                jnp.int32(pf.start), jnp.int32(pf.width),
-                jnp.asarray(self._tables[pf.slot]),
-            )
+            with profiler_span(
+                "serve.prefill", rid=pf.rid, start=pf.start, width=pf.width,
+                bucket=pf.bucket, final=pf.final,
+            ):
+                toks = np.zeros((1, pf.bucket), np.int32)
+                toks[0, : pf.width] = req.prompt[pf.start : pf.start + pf.width]
+                t0 = rec.clock() if rec.enabled else 0.0
+                logits, self.pool = self._prefill_fn(pf.bucket)(
+                    self.params, self.pool, jnp.asarray(toks),
+                    jnp.int32(pf.start), jnp.int32(pf.width),
+                    jnp.asarray(self._tables[pf.slot]),
+                )
             if pf.final:
-                tok = int(jnp.argmax(logits[0, -1]))
+                with profiler_span("serve.readback", of="prefill"):
+                    tok = int(jnp.argmax(logits[0, -1]))
                 new_tokens[pf.slot] = tok
                 if tok == self.eos_id:
                     eos_slots.add(pf.slot)
@@ -263,29 +290,34 @@ class ServeEngine:
                 )
 
         if plan.decode_slots:
-            toks = np.zeros((self.slots, 1), np.int32)
-            lengths = np.zeros((self.slots,), np.int32)
-            tables = np.full_like(self._tables, scratch)
-            for s in plan.decode_slots:
-                req = self.slot_req[s]
-                state = self.sched.slot_state(s)
-                if req is None or state is None:
-                    raise RuntimeError(
-                        f"step {plan.index}: decode batch includes slot "
-                        f"{s} with no admitted request (statically "
-                        f"detectable as R006)"
-                    )
-                toks[s, 0] = req.output[-1]
-                lengths[s] = state.length
-                tables[s] = self._tables[s]
-            t0 = rec.clock() if rec.enabled else 0.0
-            logits, self.pool = self._decode(
-                self.params, self.pool,
-                self._slot_sharded(jnp.asarray(toks)),
-                self._slot_sharded(jnp.asarray(lengths)),
-                self._slot_sharded(jnp.asarray(tables)),
-            )
-            nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
+            with profiler_span(
+                "serve.decode", slots=len(plan.decode_slots)
+            ) as span:
+                toks = np.zeros((self.slots, 1), np.int32)
+                lengths = np.zeros((self.slots,), np.int32)
+                tables = np.full_like(self._tables, scratch)
+                for s in plan.decode_slots:
+                    req = self.slot_req[s]
+                    state = self.sched.slot_state(s)
+                    if req is None or state is None:
+                        raise RuntimeError(
+                            f"step {plan.index}: decode batch includes slot "
+                            f"{s} with no admitted request (statically "
+                            f"detectable as R006)"
+                        )
+                    toks[s, 0] = req.output[-1]
+                    lengths[s] = state.length
+                    tables[s] = self._tables[s]
+                span.set_metadata(kv_tokens=int(lengths.sum()))
+                t0 = rec.clock() if rec.enabled else 0.0
+                logits, self.pool = self._decode(
+                    self.params, self.pool,
+                    self._slot_sharded(jnp.asarray(toks)),
+                    self._slot_sharded(jnp.asarray(lengths)),
+                    self._slot_sharded(jnp.asarray(tables)),
+                )
+            with profiler_span("serve.readback", of="decode"):
+                nxt = np.asarray(jnp.argmax(logits[:, -1], axis=-1), np.int32)
             if rec.enabled:
                 rec.emit(
                     f"step{plan.index}/decode[{len(plan.decode_slots)}]",
@@ -298,7 +330,8 @@ class ServeEngine:
                 if tok == self.eos_id:
                     eos_slots.add(s)
 
-        res = self.sched.commit(plan, frozenset(eos_slots))
+        with profiler_span("serve.commit"):
+            res = self.sched.commit(plan, frozenset(eos_slots))
         dur = iv.stop()
         self.sched.advance(dur)
         t_end = self.sched.clock

@@ -533,9 +533,12 @@ def run_timed_step(jitted, state, batch, recorder, name: str, **labels):
     recording is enabled, so the measured durations are bit-identical to
     the old code path (see repro.obs.record).
 
+    Under a profiler session the step is the span ``train.step``.
+
     Returns ``(state, metrics, loss, dt_seconds)``.
     """
-    iv = recorder.interval(name, "host", kind="train-step", **labels)
+    iv = recorder.interval(name, "host", kind="train-step",
+                           layer="train.step", **labels)
     state, metrics = jitted(state, batch)
     loss = float(metrics["loss"])  # host sync: the step is truly done
     dt = iv.stop()
