@@ -107,8 +107,10 @@ class ServeEngine:
             return paged.decode_batch(p, pool, t, ln, tb, self.cfg, scfg)
 
         # the programs' names (``jit_serve_decode``, ``jit_serve_prefill``)
-        # are what a profiler trace tells them apart by
-        self._decode = jax.jit(serve_decode)
+        # are what a profiler trace tells them apart by.  Decode donates the
+        # pool (every call site rebinds ``self.pool`` to the result), so
+        # its layer loop updates the pool in place
+        self._decode = jax.jit(serve_decode, donate_argnums=(1,))
         self._prefill_cache: dict[int, Callable] = {}
         # duration source only — scheduling time is sched.clock (see module
         # docstring); injectable for deterministic tests.  Telemetry goes

@@ -159,6 +159,37 @@ def prefill_chunk(params, pool, tokens, start, width, table_row,
     return logits, {"k": pk, "v": pv}
 
 
+def _decode_attention(attn_p, h, cfg, pool_k, pool_v, layer, *,
+                      positions, write_bi, write_off, tables, mask):
+    """One new token per slot: write its K/V into layer ``layer`` of the
+    whole stacked pool in place, and attend over each slot's gathered view
+    by KV-head group (``_sdpa_dense`` on ``_group_q``, no repeat of the
+    view to H heads; with K == H it is plain MHA).
+
+    h: (S, 1, d); pool_k/pool_v: (num_layers, num_blocks, bs, K, hd);
+    write_bi/write_off: (S,); tables: (S, max_blocks); mask: (S, 1, 1, 1,
+    view_len).  Returns (attn_out, pool_k, pool_v).
+    """
+    cdt = jnp.dtype(cfg.compute_dtype)
+    s = h.shape[0]
+    q, k, v = L._project_qkv(attn_p, h, cfg, positions)
+    pool_k = pool_k.at[layer, write_bi, write_off].set(
+        k[:, 0].astype(pool_k.dtype)
+    )
+    pool_v = pool_v.at[layer, write_bi, write_off].set(
+        v[:, 0].astype(pool_v.dtype)
+    )
+    kv_heads = k.shape[2]
+    kv_shape = (s, tables.shape[1] * pool_k.shape[2]) + k.shape[2:]
+    k_view = pool_k[layer, tables].reshape(kv_shape)
+    v_view = pool_v[layer, tables].reshape(kv_shape)
+    out = L._sdpa_dense(L._group_q(q, kv_heads), k_view, v_view, mask, cfg)
+    y = jnp.einsum(
+        "bqhk,hkd->bqd", out.reshape(q.shape), attn_p["wo"].astype(cdt)
+    )
+    return y, pool_k, pool_v
+
+
 def decode_batch(params, pool, tokens, lengths, tables,
                  cfg: ArchConfig, scfg: ServeConfig,
                  ) -> tuple[jax.Array, dict[str, jax.Array]]:
@@ -170,6 +201,11 @@ def decode_batch(params, pool, tokens, lengths, tables,
     length 0 and an all-scratch table row — they compute garbage that only
     ever writes to the scratch block.  Returns (logits (S, 1, vocab),
     new pool).
+
+    The whole pool is the layer scan's carry, not its scanned input and
+    output: each layer writes one token per slot into it and gathers its
+    view from it, so a caller that donates ``pool`` gets the update in
+    place (the engine's decode program does).
     """
     s = tokens.shape[0]
     bs = scfg.block_size
@@ -179,26 +215,28 @@ def decode_batch(params, pool, tokens, lengths, tables,
     kv_pos = jnp.arange(scfg.view_len, dtype=jnp.int32)
     # reference parity: attention_decode masks ki <= cache_len (the
     # just-written position inclusive)
-    mask = (kv_pos[None, :] <= lengths[:, None])[:, None, None]  # (S,1,1,V)
+    mask = (kv_pos[None, :] <= lengths[:, None])[:, None, None, None]
 
     cdt = jnp.dtype(cfg.compute_dtype)
     h = L.embed(params["embed"], tokens, cdt)
 
-    def body(hh, xs):
-        block_p, (lk, lv) = xs
+    def body(carry, xs):
+        hh, pk, pv = carry
+        block_p, layer = xs
         n = L.rmsnorm(hh, block_p["norm1"], cfg.norm_eps, cdt)
-        a, nk, nv = _paged_attention(
-            block_p["attn"], n, cfg, lk, lv,
+        a, pk, pv = _decode_attention(
+            block_p["attn"], n, cfg, pk, pv, layer,
             positions=positions, write_bi=write_bi, write_off=write_off,
             tables=tables, mask=mask,
         )
         hh = hh + a
         n = L.rmsnorm(hh, block_p["norm2"], cfg.norm_eps, cdt)
         hh = hh + _ffn(block_p, n, cfg)
-        return hh, (nk, nv)
+        return (hh, pk, pv), None
 
-    h, (pk, pv) = jax.lax.scan(
-        body, h, (params["blocks"], (pool["k"], pool["v"]))
+    layers = jnp.arange(pool["k"].shape[0], dtype=jnp.int32)
+    (h, pk, pv), _ = jax.lax.scan(
+        body, (h, pool["k"], pool["v"]), (params["blocks"], layers)
     )
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps, cdt)
     w, transpose = _head_weight(params, cfg)

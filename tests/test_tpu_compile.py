@@ -80,12 +80,15 @@ def test_serve_decode_and_prefill_compile(one_chip):
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32, sharding=rep)
     mb = scfg.max_blocks_per_slot
 
+    # donated, as the engine's decode program donates it
     decode = jax.jit(lambda p, pl, t, ln, tb: paged.decode_batch(
-        p, pl, t, ln, tb, cfg, scfg))
+        p, pl, t, ln, tb, cfg, scfg), donate_argnums=(1,))
     compiled = decode.lower(
         params, pool, i32(8, 1), i32(8), i32(8, mb)
     ).compile()
     assert _device_bytes(compiled) < HBM_BYTES
+    pool_bytes = sum(a.size * a.dtype.itemsize for a in pool.values())
+    assert compiled.memory_analysis().alias_size_in_bytes >= pool_bytes
 
     prefill = jax.jit(lambda p, pl, t, s, w, row: paged.prefill_chunk(
         p, pl, t, s, w, row, 0, cfg, scfg))
