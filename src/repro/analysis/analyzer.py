@@ -53,7 +53,7 @@ def _synthetic_moe_a2a(cfg, strategy, micro_batch: int, seq: int):
             sum(
                 1
                 for i in range(k * per, (k + 1) * per)
-                if i % cfg.moe.every_k == cfg.moe.offset
+                if cfg.is_moe_layer(i)
             )
             for k in range(V)
         ],

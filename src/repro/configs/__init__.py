@@ -14,6 +14,7 @@ from repro.configs import (  # noqa: F401
     jamba_1_5_large_398b,
     seamless_m4t_large_v2,
     mamba2_2_7b,
+    moonlight_16b_a3b,
 )
 from repro.configs.base import (  # noqa: F401
     ArchConfig,

@@ -28,20 +28,52 @@ class MoEConfig:
     num_experts: int
     top_k: int
     d_ff_expert: int
+    # shared experts run on every token as one gated MLP of width
+    # ``num_shared_experts * d_ff_expert``
     num_shared_experts: int = 0
     # MoE replaces the dense FFN in layers where ``layer_idx % every_k == offset``.
     every_k: int = 1
     offset: int = 0
+    # Capacity dispatch of the explicit all-to-all layer (``impl ==
+    # "ep_a2a"``, ``repro.dist.ep_a2a``): tokens are dispatched within
+    # groups of ``group_size`` into ``capacity_factor``-sized expert slots.
+    # The one-chip layer (``repro.models.moe``) is dropless and reads neither.
     capacity_factor: float = 1.25
-    # Tokens are dispatched within groups of this many tokens (GShard-style
-    # grouped dispatch keeps the dispatch mask O(N * k * group) instead of
-    # O(N * E * C)).
     group_size: int = 512
+    # Router: "softmax" scores (GShard/Switch; balance loss on the top-1
+    # fraction over the batch) or "sigmoid" scores (DeepSeek-V3: the
+    # sequence-wise balance loss over the top-k choices of each sequence).
+    score_func: str = "softmax"
+    # balance loss coefficient (Switch ``aux`` or DeepSeek-V3's alpha)
     router_aux_loss: float = 0.01
-    # "einsum": GSPMD places the collectives (baseline).  "ep_a2a": explicit
-    # shard_map all-to-all expert parallelism — experts sharded over `data`,
-    # expert FFN width over `model`; only routed activations move.
-    impl: str = "einsum"
+    # the chosen experts' weights are their scores normalised to sum 1
+    # (``norm_topk_prob``), times this
+    routed_scaling_factor: float = 1.0
+    # Auxiliary-loss-free balancing (DeepSeek-V3 "noaux_tc"): a per-expert
+    # correction bias added to the scores for selection only, moved by
+    # ``bias_update_rate * sign(mean load - load)`` after each train step.
+    # 0 -> no bias.
+    bias_update_rate: float = 0.0
+    # (first, count): the routed experts whose weights this layer holds.
+    # The router still scores all ``num_experts``; assignments to experts
+    # outside the range contribute nothing here (they are another chip's
+    # share under expert parallelism).  None -> every expert.
+    experts_held: Optional[tuple[int, int]] = None
+    # "dropless": ``repro.models.moe``'s grouped product over the held
+    # experts, every token on the device that holds it.  "ep_a2a": explicit
+    # shard_map all-to-all expert parallelism (``repro.dist.ep_a2a``) —
+    # experts sharded over `data`, expert FFN width over `model`; only
+    # routed activations move, in capacity slots.
+    impl: str = "dropless"
+
+    @property
+    def held(self) -> tuple[int, int]:
+        """(first, count) of the routed experts held here."""
+        return self.experts_held or (0, self.num_experts)
+
+    @property
+    def d_ff_shared(self) -> int:
+        return self.num_shared_experts * self.d_ff_expert
 
 
 @dataclass(frozen=True)
@@ -79,6 +111,17 @@ class ArchConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
     moe: Optional[MoEConfig] = None
+    # leading layers that keep the dense FFN (DeepSeek ``first_k_dense_replace``)
+    first_k_dense: int = 0
+    # Multi-head latent attention (DeepSeek-V2 §2.1), used when
+    # ``kv_lora_rank > 0``: keys and values come from a normalised latent
+    # of this width; each head's query/key is ``qk_nope_head_dim`` content
+    # dims plus ``qk_rope_head_dim`` rotary dims (the rotary key is one
+    # vector shared by all heads); values are ``v_head_dim`` wide.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     mamba: Optional[MambaConfig] = None
     # hybrid interleave: layer i is attention iff i % attn_every == attn_offset
     attn_every: int = 1
@@ -134,14 +177,51 @@ class ArchConfig:
     def is_attention_free(self) -> bool:
         return self.family == "ssm"
 
+    @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of one head's query and key."""
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.resolved_head_dim
+
+    @property
+    def v_dim(self) -> int:
+        """Width of one head's value (and attention output)."""
+        return self.v_head_dim if self.is_mla else self.resolved_head_dim
+
+    def attn_params(self) -> int:
+        """Matrix parameters of one attention layer."""
+        d, H = self.d_model, self.num_heads
+        if self.is_mla:
+            r, rope = self.kv_lora_rank, self.qk_rope_head_dim
+            return (d * H * self.qk_head_dim + d * (r + rope)
+                    + r * H * (self.qk_nope_head_dim + self.v_head_dim)
+                    + H * self.v_head_dim * d)
+        hd = self.resolved_head_dim
+        return d * (self.num_heads * hd) * 2 + d * (self.num_kv_heads * hd) * 2
+
+    def kv_elems_per_token(self) -> int:
+        """Cached key and value elements of one position in one layer
+        (MLA caches the expanded per-head keys and values)."""
+        if self.is_mla:
+            return self.num_heads * (self.qk_head_dim + self.v_head_dim)
+        return 2 * self.num_kv_heads * self.resolved_head_dim
+
+    def is_moe_layer(self, idx: int) -> bool:
+        return (self.moe is not None and idx >= self.first_k_dense
+                and idx % self.moe.every_k == self.moe.offset)
+
     def num_params(self) -> int:
         """Analytic parameter count (embedding + blocks + head)."""
         d, v = self.d_model, self.vocab_size
-        hd = self.resolved_head_dim
         n = v * d                       # token embedding
         if not self.tie_embeddings:
             n += v * d                  # output head
-        attn = d * (self.num_heads * hd) * 2 + d * (self.num_kv_heads * hd) * 2
+        attn = self.attn_params() + self.kv_lora_rank  # + the latent norm
         dense_ffn = 3 * d * self.d_ff if self.d_ff else 0
         mamba_p = 0
         if self.mamba is not None:
@@ -162,11 +242,13 @@ class ArchConfig:
             else:
                 n += mamba_p + d
             # FFN (dense or MoE) — hybrid archs attach FFN to every layer
-            if self.moe is not None and i % self.moe.every_k == self.moe.offset:
+            if self.is_moe_layer(i):
                 e = self.moe
-                n += self.moe.num_experts * 3 * d * e.d_ff_expert
-                n += e.num_shared_experts * 3 * d * e.d_ff_expert
-                n += d * self.moe.num_experts  # router
+                n += e.held[1] * 3 * d * e.d_ff_expert
+                n += 3 * d * e.d_ff_shared
+                n += d * e.num_experts  # router
+                if e.bias_update_rate:
+                    n += e.num_experts  # correction bias
             elif self.d_ff:
                 n += dense_ffn
         if self.encoder_layers:
@@ -183,9 +265,9 @@ class ArchConfig:
         e = self.moe
         total = self.num_params()
         moe_layers = len(
-            [i for i in range(self.num_layers) if i % e.every_k == e.offset]
+            [i for i in range(self.num_layers) if self.is_moe_layer(i)]
         )
-        all_experts = moe_layers * e.num_experts * 3 * self.d_model * e.d_ff_expert
+        all_experts = moe_layers * e.held[1] * 3 * self.d_model * e.d_ff_expert
         active = moe_layers * (e.top_k + e.num_shared_experts) * 3 * self.d_model * e.d_ff_expert
         return total - all_experts + active
 
@@ -276,6 +358,9 @@ def smoke_variant(cfg: ArchConfig) -> ArchConfig:
         "param_dtype": "float32",
         "compute_dtype": "float32",
     }
+    if cfg.is_mla:
+        changes.update(num_kv_heads=4, kv_lora_rank=32, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16)
     if cfg.moe is not None:
         changes["moe"] = dataclasses.replace(
             cfg.moe,
@@ -284,6 +369,7 @@ def smoke_variant(cfg: ArchConfig) -> ArchConfig:
             d_ff_expert=64,
             group_size=32,
             num_shared_experts=min(cfg.moe.num_shared_experts, 1),
+            experts_held=None,
         )
     if cfg.mamba is not None:
         changes["mamba"] = dataclasses.replace(

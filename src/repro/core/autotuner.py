@@ -29,16 +29,15 @@ def layer_cost_from_config(
 ) -> LayerCost:
     """Analytic per-layer cost for one microbatch, per tp shard."""
     d = cfg.d_model
-    hd = cfg.resolved_head_dim
-    qkv = 2.0 * batch * seq * d * (cfg.num_heads + 2 * cfg.num_kv_heads) * hd
-    attn = 4.0 * batch * seq * seq * cfg.num_heads * hd  # scores + out
-    proj = 2.0 * batch * seq * cfg.num_heads * hd * d
+    # q/k/v and output projections; scores + out over qk and v widths
+    proj = 2.0 * batch * seq * cfg.attn_params()
+    attn = 2.0 * batch * seq * seq * cfg.num_heads * (cfg.qk_head_dim + cfg.v_dim)
     if cfg.moe is not None:
         e = cfg.moe
         ffn = 6.0 * batch * seq * d * e.d_ff_expert * (e.top_k + e.num_shared_experts)
     else:
         ffn = 6.0 * batch * seq * d * cfg.d_ff
-    flops = (qkv + attn + proj + ffn) / tp
+    flops = (proj + attn + ffn) / tp
     act_bytes = dtype_bytes * batch * seq * d
     layer_params = (
         cfg.num_params() - 2 * cfg.vocab_size * d

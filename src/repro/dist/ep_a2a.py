@@ -1,14 +1,13 @@
 """Expert-parallel MoE FFN with explicit all-to-all dispatch (shard_map).
 
-The einsum MoE (``repro.models.moe.moe_ffn``) lets GSPMD place the
-collectives: the combine einsum contracts a ``model``-sharded expert axis
-into an all-reduce over *dense* activations.  This module is the explicit
-alternative the §Perf hillclimb iterates toward: experts are sharded over
-``data`` (expert parallelism), routing happens per data shard, and only the
-*routed* capacity slots move — two all-to-alls (dispatch, return) instead
-of a dense all-reduce.  Routing, capacity assignment, and the expert FFN
-math are identical to the einsum path, so at capacity parity (no dropped
-tokens, aligned token groups) the two implementations agree numerically.
+Experts are sharded over ``data`` (expert parallelism), routing happens per
+data shard, and only the *routed* capacity slots move — two all-to-alls
+(dispatch, return).  The all-to-all needs every shard to send the same
+shape, so tokens are dispatched in GShard's capacity layout: per routing
+group of ``group_size`` tokens, each expert gets ``capacity(...)`` slots
+and assignments past them are dropped.  This is the one place the capacity
+layout lives; on one chip ``repro.models.moe`` is dropless, and the two
+agree where no assignment is dropped (softmax routers only).
 
 ``moe_a2a_bytes`` is the simulator-facing twin: the per-device payload of
 one dispatch (or return) all-to-all, consumed by the comm-volume hooks in
@@ -16,12 +15,22 @@ one dispatch (or return) all-to-all, consumed by the comm-volume hooks in
 """
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import MoEConfig
+
+
+def capacity(moe: MoEConfig, group: int) -> int:
+    """Expert slots per routing group: ``ceil(top_k * group / E * cf)``;
+    assignments past them are dropped."""
+    return max(
+        1, int(math.ceil(moe.top_k * group / moe.num_experts * moe.capacity_factor))
+    )
 
 
 def _global_group(moe: MoEConfig, n_tok: int) -> int:
@@ -60,15 +69,20 @@ def moe_ffn_ep_a2a(
     p, x, moe: MoEConfig, compute_dtype, mesh: Mesh,
     data_axis: str = "data", model_axis: str = "model",
 ):
-    """x: (B, S, D) sharded ``P(data)`` on batch -> (y, aux_loss).
+    """x: (B, S, D) sharded ``P(data)`` on batch -> (y, aux_loss, load),
+    ``load`` the assignments to each expert over every shard's tokens.
 
     Parameter layout (the ``impl == "ep_a2a"`` axes of ``init_moe``):
     router replicated; wg/wu ``P(data, None, model)``; wd
     ``P(data, model, None)`` — experts over ``data``, FFN width over
     ``model`` (Megatron column/row split, one psum over ``model``).
     """
-    from repro.models.moe import capacity  # late: moe.py imports this module
-
+    if (moe.score_func, moe.experts_held, moe.routed_scaling_factor,
+            moe.bias_update_rate) != ("softmax", None, 1.0, 0.0):
+        raise ValueError(
+            "the all-to-all layer routes by softmax over every expert, "
+            "unscaled and without a correction bias"
+        )
     cdt = jnp.dtype(compute_dtype)
     sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
     dp = sizes[data_axis]
@@ -144,7 +158,8 @@ def moe_ffn_ep_a2a(
         me = jax.lax.pmean(jnp.mean(probs, axis=(0, 1)), data_axis)
         ce = jax.lax.pmean(jnp.mean(oh_e[:, :, 0, :], axis=(0, 1)), data_axis)
         aux = moe.router_aux_loss * E * jnp.sum(me * ce)
-        return y, aux
+        load = jax.lax.psum(jnp.sum(oh_e, axis=(0, 1, 2)), data_axis)
+        return y, aux, load
 
     in_specs = (
         P(),                          # router (replicated)
@@ -153,14 +168,13 @@ def moe_ffn_ep_a2a(
         P(data_axis, model_axis, None),   # wd (E, F, D)
         P(data_axis, None, None),         # x  (B, S, D)
     )
-    y, aux = shard_map(
+    return shard_map(
         body,
         mesh=mesh,
         in_specs=in_specs,
-        out_specs=(P(data_axis, None, None), P()),
+        out_specs=(P(data_axis, None, None), P(), P()),
         check_vma=False,
     )(p["router"], p["wg"], p["wu"], p["wd"], x)
-    return y, aux
 
 
 # ---------------------------------------------------------------------------

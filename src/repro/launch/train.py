@@ -557,7 +557,7 @@ def main() -> None:
     ap.add_argument("--d-model", type=int, default=0,
                     help="override d_model (with --smoke)")
     ap.add_argument("--layers", type=int, default=0)
-    ap.add_argument("--moe-impl", choices=["einsum", "ep_a2a"], default=None,
+    ap.add_argument("--moe-impl", choices=["dropless", "ep_a2a"], default=None,
                     help="MoE execution strategy (ep_a2a = explicit "
                          "all-to-all expert parallelism, repro.dist.ep_a2a)")
     args = ap.parse_args()

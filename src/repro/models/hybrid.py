@@ -135,7 +135,7 @@ def apply_superblock(p, x, cfg: ArchConfig, *, positions, caches=None, decode_le
             continue
         h = L.rmsnorm(x, p["norm2"][j], cfg.norm_eps, cdt)
         if ffn == "moe":
-            y, a = M.moe_ffn(_take(p["moe"], i_moe), h, cfg.moe, cdt)
+            y, a, _ = M.moe_ffn(_take(p["moe"], i_moe), h, cfg.moe, cdt)
             aux = aux + a
             i_moe += 1
         else:

@@ -1,4 +1,5 @@
-"""Shared model substrate: norms, RoPE, GQA attention (+KV cache), MLPs.
+"""Shared model substrate: norms, RoPE, GQA and latent attention (+KV
+cache), MLPs.
 
 Parameter convention: every ``init_*`` returns ``(params, axes)`` where
 ``axes`` mirrors ``params`` and holds the logical-axis tuple of each leaf
@@ -67,6 +68,8 @@ def apply_rope(x, positions, theta: float):
 
 
 def init_attention(key, cfg):
+    if cfg.is_mla:
+        return _init_mla(key, cfg)
     d, H, K, hd = cfg.d_model, cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 4)
@@ -92,7 +95,55 @@ def init_attention(key, cfg):
     return params, axes
 
 
+def _init_mla(key, cfg):
+    """Latent attention (DeepSeek-V2 §2.1) without a query latent."""
+    d, H = cfg.d_model, cfg.num_heads
+    r, nope = cfg.kv_lora_rank, cfg.qk_nope_head_dim
+    rope, vd = cfg.qk_rope_head_dim, cfg.v_head_dim
+    dt = jnp.dtype(cfg.param_dtype)
+    ks = jax.random.split(key, 4)
+    params = {
+        "wq": _init_dense(ks[0], (d, H, nope + rope), d, dt),
+        "wkv_a": _init_dense(ks[1], (d, r + rope), d, dt),
+        "kv_norm": jnp.ones((r,), dt),
+        "wkv_b": _init_dense(ks[2], (r, H, nope + vd), r, dt),
+        "wo": _init_dense(ks[3], (H, vd, d), H * vd, dt),
+    }
+    axes = {
+        "wq": ("embed", "heads", "head_dim"),
+        "wkv_a": ("embed", None),
+        "kv_norm": (None,),
+        "wkv_b": (None, "heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+    }
+    return params, axes
+
+
+def _project_mla(p, x, cfg, positions):
+    """q = W_Q h; [c_KV; k_R] = W_DKV h; c_KV = RMSNorm(c_KV);
+    [k_C; v] = W_UKV c_KV; rotary on q_R and on the one k_R every head
+    shares.  Returns per-head q, k = [q_C; q_R], [k_C; k_R] (nope + rope
+    wide, so that q.k / sqrt(nope + rope) is the published score) and v."""
+    cdt = jnp.dtype(cfg.compute_dtype)
+    nope, r = cfg.qk_nope_head_dim, cfg.kv_lora_rank
+    q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cdt))
+    ckv = jnp.einsum("bsd,dr->bsr", x, p["wkv_a"].astype(cdt))
+    c = rmsnorm(ckv[..., :r], p["kv_norm"], cfg.norm_eps, cdt)
+    kv = jnp.einsum("bsr,rhk->bshk", c, p["wkv_b"].astype(cdt))
+    k_c, v = kv[..., :nope], kv[..., nope:]
+    q_r = apply_rope(q[..., nope:], positions, cfg.rope_theta)
+    k_r = apply_rope(ckv[..., None, r:], positions, cfg.rope_theta)
+    q = jnp.concatenate([q[..., :nope], q_r], axis=-1)
+    k = jnp.concatenate(
+        [k_c, jnp.broadcast_to(k_r, k_c.shape[:-1] + k_r.shape[-1:])], axis=-1
+    )
+    q = shard_hint(q, ("batch", "seq_q", "act_heads", None), "q")
+    return q, k, v
+
+
 def _project_qkv(p, x, cfg, positions):
+    if cfg.is_mla:
+        return _project_mla(p, x, cfg, positions)
     cdt = jnp.dtype(cfg.compute_dtype)
     q = jnp.einsum("bsd,dhk->bshk", x, p["wq"].astype(cdt))
     k = jnp.einsum("bsd,dhk->bshk", x, p["wk"].astype(cdt))
@@ -154,24 +205,27 @@ def _sdpa_blockwise(
 ):
     """Grouped online-softmax attention, scanning KV in blocks (jnp flash).
 
-    qg: (B,Sq,K,G,hd); k,v: (B,Skv,K,hd).  Memory is O(Sq * block_kv)
-    instead of O(Sq * Skv).  Causal masking uses global positions: query i
+    qg: (B,Sq,K,G,hd); k: (B,Skv,K,hd); v: (B,Skv,K,vd).  Memory is
+    O(Sq * block_kv) instead of O(Sq * Skv); the block body is
+    rematerialized, so the backward pass keeps each block's carry and not
+    its (Sq, block_kv) scores.  Causal masking uses global positions: query i
     attends to kv j iff j <= i + q_offset.  This is the XLA-side counterpart
     of the Pallas flash kernel in ``repro.kernels.flash_attention`` (which is
     the TPU-target artifact).
     """
     scale = 1.0 / math.sqrt(qg.shape[-1])
     b, sq, kh, g, hd = qg.shape
-    skv = k.shape[1]
+    skv, vd = k.shape[1], v.shape[-1]
     blk = min(cfg.attn_block_kv, skv)
     assert skv % blk == 0, f"kv len {skv} % block {blk} != 0"
     nblk = skv // blk
     kb = jnp.moveaxis(k.reshape(b, nblk, blk, kh, hd), 1, 0)
-    vb = jnp.moveaxis(v.reshape(b, nblk, blk, kh, hd), 1, 0)
+    vb = jnp.moveaxis(v.reshape(b, nblk, blk, kh, vd), 1, 0)
     qi = jnp.arange(sq) + q_offset  # global query positions
 
+    @jax.checkpoint
     def body(carry, inputs):
-        m, l, acc = carry  # (B,K,G,Sq), (B,K,G,Sq), (B,K,G,Sq,hd)
+        m, l, acc = carry  # (B,K,G,Sq), (B,K,G,Sq), (B,K,G,Sq,vd)
         jblk, kj, vj = inputs
         s = (
             jnp.einsum("bqkgd,bskd->bkgqs", qg, kj).astype(jnp.float32)
@@ -202,7 +256,7 @@ def _sdpa_blockwise(
 
     m0 = jnp.full((b, kh, g, sq), -jnp.inf, jnp.float32)
     l0 = jnp.zeros((b, kh, g, sq), jnp.float32)
-    a0 = jnp.zeros((b, kh, g, sq, hd), jnp.float32)
+    a0 = jnp.zeros((b, kh, g, sq, vd), jnp.float32)
     (m, l, acc), _ = jax.lax.scan(
         body, (m0, l0, a0), (jnp.arange(nblk), kb, vb)
     )
@@ -216,11 +270,12 @@ def _sdpa_blockwise(
 def _sdpa(
     q, k, v, mask, cfg, *, q_offset: int = 0, kv_valid=None, bidirectional=False
 ):
-    """q: (B,Sq,H,hd); k,v: (B,Skv,K,hd) with K the *stored* kv-head count.
+    """q: (B,Sq,H,hd); k: (B,Skv,K,hd), v: (B,Skv,K,vd) with K the
+    *stored* kv-head count.
 
     Repeats KV to ``_kv_target`` heads (H baseline; the TP width when
     ``cfg.gqa_repeat_to`` is set) and runs the grouped attention paths.
-    Returns (B,Sq,H,hd).
+    Returns (B,Sq,H,vd).
     """
     b, sq, h, hd = q.shape
     if cfg.attn_impl == "proxy":
@@ -250,7 +305,7 @@ def _sdpa(
             qg, k, v, cfg, q_offset=q_offset, kv_valid=kv_valid,
             bidirectional=bidirectional,
         )
-        return out.reshape(b, sq, h, hd)
+        return out.reshape(b, sq, h, -1)
     if mask is None:
         if bidirectional:
             mask = jnp.ones((1, 1, 1, 1), bool)
@@ -260,7 +315,7 @@ def _sdpa(
                 mask = mask & (jnp.arange(skv)[None, None, None, :] < kv_valid)
     # grouped mask shape: (B, 1[K], 1[G], Sq, Skv)
     out = _sdpa_dense(qg, k, v, mask[:, :, None], cfg)
-    return out.reshape(b, sq, h, hd)
+    return out.reshape(b, sq, h, -1)
 
 
 def causal_mask(sq: int, skv: int, offset: int = 0):
@@ -304,18 +359,21 @@ def cross_kv_from_memory(p, memory, cfg):
 
 
 def init_kv_cache(batch: int, max_len: int, cfg, dtype):
-    K, hd = cfg.num_kv_heads, cfg.resolved_head_dim
-    shape = (batch, max_len, K, hd)
+    """Per-position keys and values; latent attention caches its expanded
+    per-head keys (nope + rope wide) and values."""
+    K = cfg.num_kv_heads
+    k_shape = (batch, max_len, K, cfg.qk_head_dim)
+    v_shape = (batch, max_len, K, cfg.v_dim)
     if getattr(cfg, "kv_cache_dtype", "bfloat16") == "int8":
         return {
-            "k": jnp.zeros(shape, jnp.int8),
-            "v": jnp.zeros(shape, jnp.int8),
+            "k": jnp.zeros(k_shape, jnp.int8),
+            "v": jnp.zeros(v_shape, jnp.int8),
             "k_scale": jnp.zeros((batch, max_len, K, 1), jnp.bfloat16),
             "v_scale": jnp.zeros((batch, max_len, K, 1), jnp.bfloat16),
         }
     return {
-        "k": jnp.zeros(shape, dtype),
-        "v": jnp.zeros(shape, dtype),
+        "k": jnp.zeros(k_shape, dtype),
+        "v": jnp.zeros(v_shape, dtype),
     }
 
 

@@ -121,6 +121,11 @@ def check_pipelineable(
         raise ValueError(
             f"{cfg.name}: vlm patch projector not pipeline-partitionable"
         )
+    if cfg.first_k_dense or (cfg.moe is not None and cfg.moe.bias_update_rate):
+        raise ValueError(
+            f"{cfg.name}: the pipeline partitions one homogeneous block stack "
+            f"and carries no router correction bias"
+        )
     V = pp_stages * vstages
     if V < 1 or cfg.num_layers % V != 0:
         raise ValueError(
@@ -225,7 +230,7 @@ def stage_fns(cfg: ArchConfig, microbatches: int):
         positions = jnp.broadcast_to(
             jnp.arange(s, dtype=jnp.int32), (b, s)
         )
-        y, aux = transformer.apply_block(
+        y, aux, _ = transformer.apply_block(
             block_p, h, cfg, positions=positions
         )
         return y, jnp.asarray(aux, jnp.float32) * inv_m
@@ -364,8 +369,7 @@ def moe_layers_per_vstage(plan: PipelinePlan) -> list[int]:
             sum(
                 1
                 for i in range(lo, lo + per)
-                if cfg.moe is not None
-                and i % cfg.moe.every_k == cfg.moe.offset
+                if cfg.is_moe_layer(i)
             )
         )
     return out

@@ -47,10 +47,6 @@ DEFAULT_RULES: dict[str, tuple[tuple[str, ...], ...]] = {
     "embed": ((),),
     "act_heads": (("model",), ()),
     "act_ffn": (("model",), ()),
-    "act_experts": (("model",), ()),
-    "group": (("pod", "data"), ("data",), ()),  # MoE token groups
-    "expert_group": (("pod", "data"), ("data",), ()),  # post-dispatch groups
-    "capacity": ((),),
     # parameters
     "vocab": (("model",), ()),
     "heads": (("model",), ()),
@@ -60,8 +56,6 @@ DEFAULT_RULES: dict[str, tuple[tuple[str, ...], ...]] = {
     "experts": (("model",), ()),
     "expert_ffn": ((),),
     "expert_embed": ((),),
-    "act_expert_embed": ((),),
-    "act_expert_ffn": ((),),
     # explicit-EP (shard_map a2a) weight layout
     "experts_ep": (("data",), ()),
     "expert_ffn_ep": (("model",), ()),

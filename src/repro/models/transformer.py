@@ -1,9 +1,11 @@
 """Decoder-only transformer stack (dense / moe / vlm families).
 
-The layer stack is a ``lax.scan`` over parameters stacked on a leading
+Each layer stack is a ``lax.scan`` over parameters stacked on a leading
 ``layers`` dim (initialized with ``jax.vmap``), so HLO size and compile time
 are depth-independent — essential for dry-running 80-layer models on a CPU
-host.  Remat policy wraps the scan body.
+host.  Remat policy wraps the scan body.  A config with
+``first_k_dense`` leading dense layers has two stacks, scanned in turn:
+``params["dense_blocks"]`` (dense FFN) then ``params["blocks"]``.
 """
 from __future__ import annotations
 
@@ -22,11 +24,7 @@ from repro.models.sharding import shard_hint
 # ---------------------------------------------------------------------------
 
 
-def _is_moe_layer(cfg: ArchConfig, idx: int) -> bool:
-    return cfg.moe is not None and idx % cfg.moe.every_k == cfg.moe.offset
-
-
-def init_block(key, cfg: ArchConfig):
+def init_block(key, cfg: ArchConfig, dense: bool = False):
     """One decoder block: attention + FFN (dense or MoE [+ shared expert])."""
     dt = jnp.dtype(cfg.param_dtype)
     ks = jax.random.split(key, 4)
@@ -35,14 +33,12 @@ def init_block(key, cfg: ArchConfig):
     n2, n2a = L.init_rmsnorm(cfg.d_model, dt)
     params = {"attn": attn_p, "norm1": n1, "norm2": n2}
     axes = {"attn": attn_a, "norm1": n1a, "norm2": n2a}
-    if cfg.moe is not None and cfg.moe.every_k == 1:
+    if cfg.moe is not None and cfg.moe.every_k == 1 and not dense:
         moe_p, moe_a = M.init_moe(ks[1], cfg.d_model, cfg.moe, dt)
         params["moe"] = moe_p
         axes["moe"] = moe_a
         if cfg.moe.num_shared_experts:
-            sh_p, sh_a = L.init_mlp(
-                ks[2], cfg.d_model, cfg.moe.num_shared_experts * cfg.d_ff, dt
-            )
+            sh_p, sh_a = L.init_mlp(ks[2], cfg.d_model, cfg.moe.d_ff_shared, dt)
             params["shared_mlp"] = sh_p
             axes["shared_mlp"] = sh_a
     else:
@@ -63,25 +59,31 @@ def _prefix_layers(axes):
     )
 
 
+def _init_stack(key, cfg: ArchConfig, n: int, dense: bool):
+    box = {}
+
+    def one_block(k):
+        p, a = init_block(k, cfg, dense)
+        box["axes"] = a
+        return p
+
+    blocks = jax.vmap(one_block)(jax.random.split(key, n))
+    return blocks, _prefix_layers(box["axes"])
+
+
 def init_params(key, cfg: ArchConfig):
     dt = jnp.dtype(cfg.param_dtype)
     k_emb, k_blocks, k_head, k_proj = jax.random.split(key, 4)
     emb, emb_a = L.init_embedding(k_emb, cfg.vocab_size, cfg.d_model, dt)
-    block_axes_box = {}
-
-    def one_block(k):
-        p, a = init_block(k, cfg)
-        block_axes_box["axes"] = a
-        return p
-
-    blocks = jax.vmap(one_block)(jax.random.split(k_blocks, cfg.num_layers))
+    nd = cfg.first_k_dense
+    blocks, blocks_a = _init_stack(k_blocks, cfg, cfg.num_layers - nd, False)
     fn, fn_a = L.init_rmsnorm(cfg.d_model, dt)
     params = {"embed": emb, "blocks": blocks, "final_norm": fn}
-    axes = {
-        "embed": emb_a,
-        "blocks": _prefix_layers(block_axes_box["axes"]),
-        "final_norm": fn_a,
-    }
+    axes = {"embed": emb_a, "blocks": blocks_a, "final_norm": fn_a}
+    if nd:
+        params["dense_blocks"], axes["dense_blocks"] = _init_stack(
+            jax.random.fold_in(k_blocks, 1), cfg, nd, True
+        )
     if not cfg.tie_embeddings:
         params["head"] = L._init_dense(
             k_head, (cfg.d_model, cfg.vocab_size), cfg.d_model, dt
@@ -105,20 +107,26 @@ def init_params(key, cfg: ArchConfig):
 # ---------------------------------------------------------------------------
 
 
+def ffn(p, h, cfg: ArchConfig):
+    """A block's FFN on normed ``h``: (y, aux, load); ``load`` (the MoE
+    layer's assignments per expert) is None in a dense block."""
+    cdt = cfg.compute_dtype
+    if "moe" not in p:
+        return L.mlp(p["mlp"], h, cdt), 0.0, None
+    y, aux, load = M.moe_ffn(p["moe"], h, cfg.moe, cdt)
+    if "shared_mlp" in p:
+        y = y + L.mlp(p["shared_mlp"], h, cdt)
+    return y, aux, load
+
+
 def apply_block(p, x, cfg: ArchConfig, *, positions, mask=None):
+    """One block: (x, aux, load) as :func:`ffn` gives them."""
     cdt = cfg.compute_dtype
     h = L.rmsnorm(x, p["norm1"], cfg.norm_eps, cdt)
     x = x + L.attention(p["attn"], h, cfg, positions=positions, mask=mask)
     h = L.rmsnorm(x, p["norm2"], cfg.norm_eps, cdt)
-    aux = 0.0
-    if "moe" in p:
-        y, aux = M.moe_ffn(p["moe"], h, cfg.moe, cdt)
-        if "shared_mlp" in p:
-            y = y + L.mlp(p["shared_mlp"], h, cdt)
-        x = x + y
-    else:
-        x = x + L.mlp(p["mlp"], h, cdt)
-    return shard_hint(x, ("batch", "seq", "embed"), "block_out"), aux
+    y, aux, load = ffn(p, h, cfg)
+    return shard_hint(x + y, ("batch", "seq", "embed"), "block_out"), aux, load
 
 
 def _remat(fn, cfg: ArchConfig):
@@ -131,17 +139,28 @@ def _remat(fn, cfg: ArchConfig):
     return jax.checkpoint(fn)
 
 
+def stacks(params):
+    """The layer stacks in order: the leading dense one, if any, then the
+    main one."""
+    return [params[k] for k in ("dense_blocks", "blocks") if k in params]
+
+
 def run_stack(params, x, cfg: ArchConfig, *, positions, mask=None):
-    """Scan the block stack. Returns (hidden, aux_loss_sum)."""
+    """Scan the block stacks. Returns (hidden, aux_loss_sum, load): ``load``
+    (MoE layers, experts) counts each expert's assignments, None without
+    MoE layers."""
 
     def body(carry, block_p):
         h, aux = carry
-        h, a = apply_block(block_p, h, cfg, positions=positions, mask=mask)
-        return (h, aux + a), None
+        h, a, load = apply_block(block_p, h, cfg, positions=positions, mask=mask)
+        return (h, aux + a), load
 
     body = _remat(body, cfg)
-    (x, aux), _ = jax.lax.scan(body, (x, 0.0), params["blocks"])
-    return x, aux
+    aux, load = 0.0, None
+    for blocks in stacks(params):
+        (x, aux), load_s = jax.lax.scan(body, (x, aux), blocks)
+        load = load_s if load_s is not None else load
+    return x, aux, load
 
 
 # ---------------------------------------------------------------------------
@@ -175,7 +194,7 @@ def _head_weight(params, cfg: ArchConfig):
 
 def loss_fn(params, batch, cfg: ArchConfig):
     h, positions, text_start = _embed_inputs(params, batch, cfg)
-    h, aux = run_stack(params, h, cfg, positions=positions)
+    h, aux, load = run_stack(params, h, cfg, positions=positions)
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps, cfg.compute_dtype)
     if text_start:
         h = h[:, text_start:]
@@ -186,7 +205,12 @@ def loss_fn(params, batch, cfg: ArchConfig):
         h, w, labels, transpose=transpose, chunk=cfg.loss_chunk, mask=mask
     )
     loss = ce + aux
-    return loss, {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": aux}
+    if load is not None:
+        first, count = cfg.moe.held
+        metrics["expert_tokens"] = load
+        metrics["held_assignments"] = jnp.sum(load[:, first:first + count])
+    return loss, metrics
 
 
 # ---------------------------------------------------------------------------
@@ -205,6 +229,21 @@ def cache_axes(cfg: ArchConfig):
     return _prefix_layers(L.kv_cache_axes(cfg))
 
 
+def _scan_cached(body, h, params, cache):
+    """Scan ``body`` over each stack with its layers' slice of the cache
+    (layers stacked on dim 0, the dense stack first)."""
+    out, lo = [], 0
+    for blocks in stacks(params):
+        n = jax.tree_util.tree_leaves(blocks)[0].shape[0]
+        part = jax.tree_util.tree_map(lambda c: c[lo:lo + n], cache)
+        h, part = jax.lax.scan(body, h, (blocks, part))
+        out.append(part)
+        lo += n
+    if len(out) == 1:
+        return h, out[0]
+    return h, jax.tree_util.tree_map(lambda *c: jnp.concatenate(c), *out)
+
+
 def prefill(params, batch, cfg: ArchConfig, max_len: int):
     """Forward pass writing the KV cache; returns (last-token logits, cache)."""
     h, positions, _ = _embed_inputs(params, batch, cfg)
@@ -219,17 +258,11 @@ def prefill(params, batch, cfg: ArchConfig, max_len: int):
         )
         hh = hh + a
         n = L.rmsnorm(hh, block_p["norm2"], cfg.norm_eps, cfg.compute_dtype)
-        if "moe" in block_p:
-            y, _ = M.moe_ffn(block_p["moe"], n, cfg.moe, cfg.compute_dtype)
-            if "shared_mlp" in block_p:
-                y = y + L.mlp(block_p["shared_mlp"], n, cfg.compute_dtype)
-            hh = hh + y
-        else:
-            hh = hh + L.mlp(block_p["mlp"], n, cfg.compute_dtype)
+        hh = hh + ffn(block_p, n, cfg)[0]
         hh = shard_hint(hh, ("batch", "seq", "embed"), "block_out")
         return hh, new_cache
 
-    h, cache = jax.lax.scan(body, h, (params["blocks"], cache))
+    h, cache = _scan_cached(body, h, params, cache)
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps, cfg.compute_dtype)
     w, transpose = _head_weight(params, cfg)
     logits = L.logits_head(w, h[:, -1:], transpose=transpose)
@@ -250,16 +283,9 @@ def decode_step(params, cache, token, cache_len, cfg: ArchConfig):
         )
         hh = hh + a
         n = L.rmsnorm(hh, block_p["norm2"], cfg.norm_eps, cdt)
-        if "moe" in block_p:
-            y, _ = M.moe_ffn(block_p["moe"], n, cfg.moe, cdt)
-            if "shared_mlp" in block_p:
-                y = y + L.mlp(block_p["shared_mlp"], n, cdt)
-            hh = hh + y
-        else:
-            hh = hh + L.mlp(block_p["mlp"], n, cdt)
-        return hh, new_cache
+        return hh + ffn(block_p, n, cfg)[0], new_cache
 
-    h, cache = jax.lax.scan(body, h, (params["blocks"], cache))
+    h, cache = _scan_cached(body, h, params, cache)
     h = L.rmsnorm(h, params["final_norm"], cfg.norm_eps, cdt)
     w, transpose = _head_weight(params, cfg)
     logits = L.logits_head(w, h, transpose=transpose)

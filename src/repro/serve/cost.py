@@ -46,23 +46,18 @@ _XKEY = {FAMILY_PREFILL: "tokens", FAMILY_DECODE: "slots"}
 # -- analytic features ----------------------------------------------------------
 
 
-def _is_moe_layer(cfg: ArchConfig, i: int) -> bool:
-    return cfg.moe is not None and i % cfg.moe.every_k == cfg.moe.offset
-
-
 def _param_bytes(cfg: ArchConfig) -> float:
     """Active-parameter bytes read per serve step (MoE: routed experts
     only — the token actually touches top_k + shared expert weights)."""
     d, v = cfg.d_model, cfg.vocab_size
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     itemsize = np.dtype(cfg.param_dtype).itemsize
-    attn = d * (H + 2 * K) * hd + H * hd * d + 2 * d
+    attn = cfg.attn_params() + 2 * d
     total = v * d
     if not cfg.tie_embeddings:
         total += d * v
     for i in range(cfg.num_layers):
         total += attn
-        if _is_moe_layer(cfg, i):
+        if cfg.is_moe_layer(i):
             e = cfg.moe
             act = e.top_k + e.num_shared_experts
             total += act * 3 * d * e.d_ff_expert + d * e.num_experts
@@ -74,14 +69,13 @@ def _param_bytes(cfg: ArchConfig) -> float:
 def _flops_per_token(cfg: ArchConfig, view: int) -> float:
     """Dense-equivalent flops of one token through the stack attending a
     ``view``-wide KV window (2 flops per MAC)."""
-    d = cfg.d_model
-    H, K, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
-    proj = 2 * d * (H + 2 * K) * hd + 2 * H * hd * d
-    attn = 2 * 2 * H * hd * view          # qk scores + pv mix
+    d, H = cfg.d_model, cfg.num_heads
+    proj = 2 * cfg.attn_params()
+    attn = 2 * H * (cfg.qk_head_dim + cfg.v_dim) * view  # qk scores + pv mix
     total = 2 * d * cfg.vocab_size        # logits head
     for i in range(cfg.num_layers):
         total += proj + attn
-        if _is_moe_layer(cfg, i):
+        if cfg.is_moe_layer(i):
             e = cfg.moe
             act = e.top_k + e.num_shared_experts
             total += act * 3 * 2 * d * e.d_ff_expert + 2 * d * e.num_experts
@@ -101,11 +95,10 @@ def serve_node_features(
     traffic (gather-read the view, scatter-write one position).
     """
     view = scfg.view_len
-    K, hd = cfg.num_kv_heads, cfg.resolved_head_dim
     kv_item = np.dtype(cfg.compute_dtype).itemsize
     flops = x * _flops_per_token(cfg, view)
     kv_bytes = (
-        cfg.num_layers * x * (view + 1) * K * hd * kv_item * 2  # k and v
+        cfg.num_layers * x * (view + 1) * cfg.kv_elems_per_token() * kv_item
     )
     return flops, _param_bytes(cfg) + float(kv_bytes)
 

@@ -43,7 +43,7 @@ import jax.numpy as jnp
 
 from repro.configs.base import ArchConfig
 from repro.models import layers as L
-from repro.models import moe as M
+from repro.models import transformer as T
 from repro.serve.policy import ServeConfig
 
 SUPPORTED_FAMILIES = ("dense", "moe")
@@ -55,6 +55,11 @@ def check_family(cfg: ArchConfig) -> None:
             f"paged serving supports text-only {SUPPORTED_FAMILIES} "
             f"families, not {cfg.family!r}"
             + (" with patches" if cfg.num_patches else "")
+        )
+    if cfg.is_mla or cfg.first_k_dense:
+        raise ValueError(
+            f"paged serving has no latent cache and one homogeneous layer "
+            f"stack: {cfg.name} has latent attention or leading dense layers"
         )
 
 
@@ -73,12 +78,7 @@ def init_pool(cfg: ArchConfig, scfg: ServeConfig) -> dict[str, jax.Array]:
 
 
 def _ffn(block_p, h, cfg: ArchConfig):
-    if "moe" in block_p:
-        y, _ = M.moe_ffn(block_p["moe"], h, cfg.moe, cfg.compute_dtype)
-        if "shared_mlp" in block_p:
-            y = y + L.mlp(block_p["shared_mlp"], h, cfg.compute_dtype)
-        return y
-    return L.mlp(block_p["mlp"], h, cfg.compute_dtype)
+    return T.ffn(block_p, h, cfg)[0]
 
 
 def _paged_attention(attn_p, h, cfg, pool_k, pool_v, *,

@@ -222,6 +222,11 @@ def make_train_step(
             params,
             updates,
         )
+        if cfg.moe is not None and cfg.moe.bias_update_rate:
+            new_params = move_router_bias(
+                new_params, params, metrics["expert_tokens"],
+                cfg.moe.bias_update_rate,
+            )
         out_metrics = {
             "loss": loss,
             "grad_norm": gnorm,
@@ -234,6 +239,21 @@ def make_train_step(
         )
 
     return train_step
+
+
+def move_router_bias(new_params, params, load, rate: float):
+    """DeepSeek-V3's auxiliary-loss-free balancing (§2.1.2): after the
+    step, each MoE layer's correction bias moves by
+    ``rate * sign(mean load - load_i)``, the loads (MoE layers, experts)
+    counted over every expert on this step's tokens.  The bias takes no
+    gradient (the router reads it under ``stop_gradient``) and no weight
+    decay: whatever the optimizer did to it is replaced."""
+    moe = params["blocks"]["moe"]
+    bias = moe["bias"] + rate * jnp.sign(
+        jnp.mean(load, axis=-1, keepdims=True) - load
+    )
+    blocks = dict(new_params["blocks"], moe=dict(new_params["blocks"]["moe"], bias=bias))
+    return dict(new_params, blocks=blocks)
 
 
 def make_pipeline_train_step(
@@ -533,7 +553,11 @@ def run_timed_step(jitted, state, batch, recorder, name: str, **labels):
     recording is enabled, so the measured durations are bit-identical to
     the old code path (see repro.obs.record).
 
-    Under a profiler session the step is the span ``train.step``.
+    Under a profiler session the step is the span ``train.step``.  With
+    MoE layers an enabled recorder also takes the step's counters:
+    ``moe.held_assignments`` (assignments computed by the held experts)
+    and ``moe.expert_tokens``, one sample per expert (device
+    ``expert<i>``) of the tokens routed to it, summed over the layers.
 
     Returns ``(state, metrics, loss, dt_seconds)``.
     """
@@ -542,4 +566,9 @@ def run_timed_step(jitted, state, batch, recorder, name: str, **labels):
     state, metrics = jitted(state, batch)
     loss = float(metrics["loss"])  # host sync: the step is truly done
     dt = iv.stop()
+    if recorder.enabled and "expert_tokens" in metrics:
+        recorder.counter("moe.held_assignments", "host",
+                         float(metrics["held_assignments"]))
+        for i, n in enumerate(jax.device_get(metrics["expert_tokens"]).sum(0)):
+            recorder.counter("moe.expert_tokens", f"expert{i}", float(n))
     return state, metrics, loss, dt

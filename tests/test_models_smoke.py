@@ -21,7 +21,7 @@ ARCHS = list_archs()
 
 
 def test_all_archs_registered():
-    assert len(ARCHS) == 10
+    assert len(ARCHS) == 11
 
 
 @pytest.mark.parametrize("arch", ARCHS)

@@ -106,10 +106,12 @@ _SCRIPT = textwrap.dedent(
                                    rtol=1e-4, atol=1e-4)
     print("scheduled_pp_ok")
 
-    # --- explicit a2a expert parallelism == einsum MoE (no drops) ---
+    # --- explicit a2a expert parallelism == the dropless layer (no drops) ---
     import dataclasses
     from repro.configs.base import MoEConfig
+    from repro.models import moe as moe_mod
     from repro.models.moe import init_moe, moe_ffn
+    moe_mod.INTERPRET = True   # the grouped product, op by op on the CPU
     from repro.models.sharding import make_ctx, use_sharding
     mesh2 = jax.make_mesh((4, 2), ("data", "model"),
                           axis_types=(jax.sharding.AxisType.Auto,) * 2)
@@ -118,7 +120,7 @@ _SCRIPT = textwrap.dedent(
     a2a = dataclasses.replace(base, impl="ep_a2a")
     pm, _ = init_moe(jax.random.PRNGKey(0), 32, base, jnp.float32)
     xm = jnp.asarray(rng.standard_normal((8, 16, 32)), jnp.float32)
-    y_ref, _ = moe_ffn(pm, xm, base, jnp.float32)
+    y_ref, _, load_ref = moe_ffn(pm, xm, base, jnp.float32)
     ctx = make_ctx(mesh2)
     with use_sharding(ctx), mesh2:
         xs2 = jax.device_put(xm, NamedSharding(mesh2, P("data", None, None)))
@@ -128,11 +130,29 @@ _SCRIPT = textwrap.dedent(
             "wu": jax.device_put(pm["wu"], NamedSharding(mesh2, P("data", None, "model"))),
             "wd": jax.device_put(pm["wd"], NamedSharding(mesh2, P("data", "model", None))),
         }
-        y2, _ = jax.jit(lambda p_, x_: moe_ffn(p_, x_, a2a, jnp.float32))(ps, xs2)
+        y2, _, load2 = jax.jit(lambda p_, x_: moe_ffn(p_, x_, a2a, jnp.float32))(ps, xs2)
         ge = jax.jit(jax.grad(lambda p_: moe_ffn(p_, xs2, a2a, jnp.float32)[0].sum()))(ps)
     assert float(jnp.max(jnp.abs(y2 - y_ref))) < 1e-3
+    np.testing.assert_array_equal(np.asarray(load2), np.asarray(load_ref))
     assert float(jnp.sum(jnp.abs(ge["wg"]))) > 0
     print("ep_a2a_ok")
+
+    # --- the dropless layer on the mesh: tokens over data, held experts
+    # over model, each device its part, summed == the one-device layer ---
+    r = jnp.asarray(rng.standard_normal(xm.shape), jnp.float32)
+    lossf = lambda p_, x_: jnp.sum(moe_ffn(p_, x_, base, jnp.float32)[0] * r)
+    g_ref = jax.grad(lossf)(pm, xm)
+    with use_sharding(ctx), mesh2:
+        pd = {k: jax.device_put(v, NamedSharding(mesh2, P("model") if k != "router" else P()))
+              for k, v in pm.items()}
+        y3, _, load3 = jax.jit(lambda p_, x_: moe_ffn(p_, x_, base, jnp.float32))(pd, xs2)
+        g3 = jax.jit(jax.grad(lossf))(pd, xs2)
+    np.testing.assert_allclose(np.asarray(y3), np.asarray(y_ref), rtol=1e-5, atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(load3), np.asarray(load_ref))
+    for k in ("router", "wg", "wu", "wd"):
+        np.testing.assert_allclose(np.asarray(g3[k]), np.asarray(g_ref[k]),
+                                   rtol=1e-4, atol=1e-5)
+    print("dropless_mesh_ok")
 
     # --- elastic re-mesh: move a sharded tree 8 -> 4 devices ---
     from repro.ft import apply_remesh, plan_remesh
@@ -165,5 +185,6 @@ def test_multidevice_stack():
     )
     assert out.returncode == 0, out.stderr[-3000:]
     for marker in ("compress_ok", "pipeline_ok", "pipeline_grad_ok",
-                   "scheduled_pp_ok", "ep_a2a_ok", "remesh_ok"):
+                   "scheduled_pp_ok", "ep_a2a_ok", "dropless_mesh_ok",
+                   "remesh_ok"):
         assert marker in out.stdout, (marker, out.stdout, out.stderr[-1500:])

@@ -83,16 +83,53 @@ def test_engine_chunked_prefill_matches_reference_dense(rng):
         )
 
 
+def _logits_of_served_tokens(eng):
+    """Wrap the engine's programs so that each served token's logits are
+    kept: the last prefill chunk's (the first token) and decode lane 0's."""
+    seen = {"prefill": [], "decode": []}
+    prefill_fn, decode = eng._prefill_fn, eng._decode
+
+    def keep(kind, fn, pick):
+        def run(*args):
+            logits, pool = fn(*args)
+            seen[kind].append(np.asarray(pick(logits), np.float64))
+            return logits, pool
+        return run
+
+    eng._prefill_fn = lambda b: keep("prefill", prefill_fn(b), lambda lg: lg[0, -1])
+    eng._decode = keep("decode", decode, lambda lg: lg[0, -1])
+    return seen
+
+
 def test_engine_matches_reference_moe(rng):
+    """Logits, not greedy tokens: the engine's logits for each served token
+    (12-token prompt in chunks of 8, then decode through the paged pool)
+    against the whole-prompt prefill and one-token decode of the reference
+    fed the same tokens.  The MoE layer is dropless, so chunking changes no
+    routing; float32 throughout, and the tolerance covers the different
+    summation order of chunked and whole-prompt attention."""
     from repro.serve import Request, ServeEngine
 
     cfg, model, params = _build("qwen3-moe-235b-a22b", seed=1)
     prompt = rng.integers(1, cfg.vocab_size, 12, dtype=np.int32)
     eng = ServeEngine(model, params, slots=2, max_len=32,
                       block_size=8, chunk=8)
+    seen = _logits_of_served_tokens(eng)
     eng.submit(Request(rid=0, prompt=prompt, max_new_tokens=4))
-    done = eng.run_until_done()
-    assert done[0].output == _reference_greedy(model, params, prompt, 4, 32)
+    out = eng.run_until_done()[0].output
+    served = [seen["prefill"][-1]] + seen["decode"]
+    assert len(out) == len(served) == 4
+
+    logits, cache = jax.jit(lambda p, t: model.prefill(p, {"tokens": t}, 32))(
+        params, jnp.asarray(prompt[None, :]))
+    ref = [np.asarray(logits[0, -1], np.float64)]
+    dec = jax.jit(model.decode)
+    for i, tok in enumerate(out[:-1]):
+        lg, cache = dec(params, cache, jnp.asarray([[tok]], jnp.int32),
+                        len(prompt) + i)
+        ref.append(np.asarray(lg[0, -1], np.float64))
+    for got, want in zip(served, ref):
+        np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
 
 def test_engine_boundary_runs_to_exactly_max_len(rng):

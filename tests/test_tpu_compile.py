@@ -124,6 +124,51 @@ def test_train_step_compiles(one_chip):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
+def test_moonlight_share_train_step_compiles(one_chip, monkeypatch):
+    """The train-moonlight-ep8-8k step: moonlight-16b-a3b at published
+    widths, 1 dense + 5 MoE layers holding experts 0-7 of 64, the
+    20480-id vocabulary slice, seq 8192, batch 1, fp32 master weights and
+    AdamW, remat ``dots``.  The compiler's own peak (``peak_memory_in_bytes``,
+    what its HBM check holds against the chip) fits 16 GiB; ``temp_size``
+    counts more than that peak, so the sum ``_device_bytes`` gives is not
+    asserted.  The held experts run as the grouped-product kernels
+    (``gmm``, and ``tgmm`` for the weights' gradients), not as capacity
+    one-hots; the CPU tests run the kernels interpreted, so this test asks
+    for the compiled ones."""
+    from repro.models import build_model
+    from repro.models import moe
+    from repro.models.sharding import make_ctx, use_sharding
+    from repro.optim import cosine_with_warmup, make_optimizer
+    from repro.train import make_sharded_train_step
+    from repro.train.step import abstract_state
+
+    monkeypatch.setattr(moe, "INTERPRET", False)
+    base = get_config("moonlight-16b-a3b")
+    cfg = dataclasses.replace(
+        base, num_layers=6, vocab_size=20480, compute_dtype="bfloat16",
+        moe=dataclasses.replace(base.moe, experts_held=(0, 8)),
+    )
+    model = build_model(cfg)
+    opt = make_optimizer("adamw", b1=0.9, b2=0.95, eps=1e-8, weight_decay=0.1)
+    step = make_sharded_train_step(
+        model, opt, cosine_with_warmup(3e-4, 20, 100000), one_chip
+    )
+    rep = NamedSharding(one_chip, P())
+    with use_sharding(make_ctx(one_chip, overrides=cfg.sharding_overrides)):
+        state = _placed(abstract_state(model, opt)[0], rep)
+        batch = {k: jax.ShapeDtypeStruct((1, 8192), jnp.int32, sharding=rep)
+                 for k in ("tokens", "labels")}
+        compiled = jax.jit(step, donate_argnums=(0,)).lower(
+            state, batch
+        ).compile()
+    mem = compiled.memory_analysis()
+    # weights, Adam's two moments: 12 B for each of the 669M parameters
+    assert mem.argument_size_in_bytes == pytest.approx(12 * 669.2e6, rel=0.01)
+    assert mem.peak_memory_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    assert "%gmm" in text and "%tgmm" in text
+
+
 def _flash(sds):
     from repro.kernels import flash_attention
 
