@@ -140,8 +140,9 @@ class ArchConfig:
     remat_policy: str = "dots"     # none | dots | full   (see repro.train.step)
     grad_accum: int = 1            # microbatch count for train_4k
     optimizer: str = "adamw"       # adamw | adafactor
-    # attention implementation: "auto" picks blockwise (online-softmax) above
-    # this many KV tokens, plain dense below it.
+    # attention implementation: "auto" picks, from this many KV tokens up,
+    # the flash kernel for causal self-attention on one TPU and blockwise
+    # (online-softmax) elsewhere, plain dense below it (layers.attn_path).
     attn_impl: str = "auto"
     attn_block_kv: int = 512
     flash_threshold: int = 8192

@@ -16,7 +16,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-from repro.models.sharding import shard_hint
+from repro.kernels.flash_attention.ops import LANES, flash_attention
+from repro.models.sharding import current_ctx, shard_hint
 
 
 def _init_dense(key, shape, scale_dim, dtype):
@@ -209,9 +210,9 @@ def _sdpa_blockwise(
     O(Sq * block_kv) instead of O(Sq * Skv); the block body is
     rematerialized, so the backward pass keeps each block's carry and not
     its (Sq, block_kv) scores.  Causal masking uses global positions: query i
-    attends to kv j iff j <= i + q_offset.  This is the XLA-side counterpart
-    of the Pallas flash kernel in ``repro.kernels.flash_attention`` (which is
-    the TPU-target artifact).
+    attends to kv j iff j <= i + q_offset.  It computes every (query, key)
+    block pair, the fully masked ones included; on one TPU, causal
+    self-attention takes the flash kernel instead (:func:`attn_path`).
     """
     scale = 1.0 / math.sqrt(qg.shape[-1])
     b, sq, kh, g, hd = qg.shape
@@ -267,15 +268,62 @@ def _sdpa_blockwise(
     )
 
 
+def attn_path(
+    cfg, sq: int, skv: int, *, platform: str, devices: int, mask=None,
+    q_offset=0, kv_valid=None, bidirectional: bool = False,
+) -> str:
+    """Which attention :func:`_sdpa` runs: "flash", "blockwise" or "dense".
+
+    "flash" (``repro.kernels.flash_attention``: scores stay in VMEM, fully
+    masked blocks are skipped forward and backward) where the input shows it
+    is sound: one TPU device, causal self-attention with no mask, offset or
+    valid length, ``sq == skv >= cfg.flash_threshold`` and a multiple of 128.
+    On a mesh of several devices the blockwise scan stays, since the kernel
+    is not partitioned.  "blockwise" for other long queries without a mask
+    (bidirectional, an offset, a valid length), and "dense" otherwise:
+    decode, masked chunks, short sequences.
+    """
+    if cfg.attn_impl == "blockwise":
+        return "blockwise"
+    if cfg.attn_impl != "auto":
+        return "dense"
+    causal_self = (
+        mask is None and not bidirectional and kv_valid is None
+        and isinstance(q_offset, int) and q_offset == 0 and sq == skv
+    )
+    if (platform == "tpu" and devices == 1 and causal_self
+            and skv >= cfg.flash_threshold and skv % LANES == 0):
+        return "flash"
+    # blockwise only pays off for long query blocks (train/prefill): for
+    # decode (Sq=1) dense scores are tiny and, crucially, a lax.scan over a
+    # sequence-sharded KV cache would force XLA to gather every block on
+    # every device, defeating split-KV sharding.
+    if sq >= 256 and skv >= cfg.flash_threshold and mask is None:
+        return "blockwise"
+    return "dense"
+
+
+def placement() -> dict:
+    """The platform and device count of the mesh the program is placed on:
+    the sharding context's, else the default backend's (inside a
+    ``shard_map`` body, which drops the context, that counts every device)."""
+    ctx = current_ctx()
+    if ctx is None:
+        return {"platform": jax.default_backend(), "devices": jax.device_count()}
+    devs = ctx.mesh.devices
+    return {"platform": devs.flat[0].platform, "devices": devs.size}
+
+
 def _sdpa(
     q, k, v, mask, cfg, *, q_offset: int = 0, kv_valid=None, bidirectional=False
 ):
     """q: (B,Sq,H,hd); k: (B,Skv,K,hd), v: (B,Skv,K,vd) with K the
-    *stored* kv-head count.
+    *stored* kv-head count.  Returns (B,Sq,H,vd).
 
-    Repeats KV to ``_kv_target`` heads (H baseline; the TP width when
-    ``cfg.gqa_repeat_to`` is set) and runs the grouped attention paths.
-    Returns (B,Sq,H,vd).
+    Runs the path :func:`attn_path` picks.  The flash kernel reads K/V by
+    KV-head group; the blockwise and dense paths repeat KV to
+    ``_kv_target`` heads (H baseline; the TP width when
+    ``cfg.gqa_repeat_to`` is set) and group the queries over them.
     """
     b, sq, h, hd = q.shape
     if cfg.attn_impl == "proxy":
@@ -284,23 +332,19 @@ def _sdpa(
         # Pallas flash kernel's analytic traffic can be substituted
         # (EXPERIMENTS.md §Perf / qwen1.5-110b prefill)
         return q * (1.0 / math.sqrt(hd))
+    skv = k.shape[1]
+    path = attn_path(
+        cfg, sq, skv, mask=mask, q_offset=q_offset, kv_valid=kv_valid,
+        bidirectional=bidirectional, **placement(),
+    )
+    if path == "flash":
+        with jax.named_scope("attn.flash"):
+            return flash_attention(q, k, v, causal=True)
     k_eff = _kv_target(cfg, k.shape[2])
     k = repeat_kv(k, k_eff)
     v = repeat_kv(v, k_eff)
     qg = _group_q(q, k_eff)
-    skv = k.shape[1]
-    # blockwise only pays off for long query blocks (train/prefill): for
-    # decode (Sq=1) dense scores are tiny and, crucially, a lax.scan over a
-    # sequence-sharded KV cache would force XLA to gather every block on
-    # every device, defeating split-KV sharding.
-    long_q = sq >= 256
-    use_blockwise = cfg.attn_impl == "blockwise" or (
-        cfg.attn_impl == "auto"
-        and long_q
-        and skv >= cfg.flash_threshold
-        and mask is None
-    )
-    if use_blockwise:
+    if path == "blockwise":
         out = _sdpa_blockwise(
             qg, k, v, cfg, q_offset=q_offset, kv_valid=kv_valid,
             bidirectional=bidirectional,

@@ -192,6 +192,15 @@ def _head_weight(params, cfg: ArchConfig):
     return params["head"], False
 
 
+def flash_layers(params, cfg: ArchConfig, seq: int):
+    """How many layers' attention takes the flash kernel at this sequence
+    length: every layer's self-attention has the same shapes, so all or
+    none (``L.attn_path``)."""
+    path = L.attn_path(cfg, seq, seq, **L.placement())
+    layers = sum(jax.tree_util.tree_leaves(b)[0].shape[0] for b in stacks(params))
+    return jnp.asarray(layers if path == "flash" else 0, jnp.int32)
+
+
 def loss_fn(params, batch, cfg: ArchConfig):
     h, positions, text_start = _embed_inputs(params, batch, cfg)
     h, aux, load = run_stack(params, h, cfg, positions=positions)
@@ -205,7 +214,8 @@ def loss_fn(params, batch, cfg: ArchConfig):
         h, w, labels, transpose=transpose, chunk=cfg.loss_chunk, mask=mask
     )
     loss = ce + aux
-    metrics = {"ce": ce, "aux": aux}
+    metrics = {"ce": ce, "aux": aux,
+               "attn_flash_layers": flash_layers(params, cfg, positions.shape[1])}
     if load is not None:
         first, count = cfg.moe.held
         metrics["expert_tokens"] = load

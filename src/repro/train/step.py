@@ -553,8 +553,9 @@ def run_timed_step(jitted, state, batch, recorder, name: str, **labels):
     recording is enabled, so the measured durations are bit-identical to
     the old code path (see repro.obs.record).
 
-    Under a profiler session the step is the span ``train.step``.  With
-    MoE layers an enabled recorder also takes the step's counters:
+    Under a profiler session the step is the span ``train.step``.  An
+    enabled recorder also takes the step's counters: ``attn.flash_layers``
+    (the layers whose attention ran the flash kernel) and, with MoE layers,
     ``moe.held_assignments`` (assignments computed by the held experts)
     and ``moe.expert_tokens``, one sample per expert (device
     ``expert<i>``) of the tokens routed to it, summed over the layers.
@@ -566,6 +567,9 @@ def run_timed_step(jitted, state, batch, recorder, name: str, **labels):
     state, metrics = jitted(state, batch)
     loss = float(metrics["loss"])  # host sync: the step is truly done
     dt = iv.stop()
+    if recorder.enabled and "attn_flash_layers" in metrics:
+        recorder.counter("attn.flash_layers", "host",
+                         float(metrics["attn_flash_layers"]))
     if recorder.enabled and "expert_tokens" in metrics:
         recorder.counter("moe.held_assignments", "host",
                          float(metrics["held_assignments"]))

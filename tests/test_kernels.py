@@ -19,46 +19,70 @@ def tol(dtype):
 # -- flash attention -------------------------------------------------------------
 
 
-@pytest.mark.parametrize("b,s,h,kh,d", [
-    (1, 128, 4, 4, 64),       # MHA, exact block
-    (2, 200, 8, 2, 64),       # GQA, ragged seq (padding path)
-    (1, 384, 6, 3, 128),      # head_dim 128, group 2
-    (2, 64, 2, 1, 32),        # MQA, small
+@pytest.mark.parametrize("b,s,h,kh,d,dv", [
+    (1, 128, 4, 4, 64, 64),       # MHA, exact block
+    (2, 256, 8, 2, 64, 64),       # GQA, group 4, two batch rows
+    (1, 384, 6, 3, 128, 128),     # head_dim 128, group 2, 3 x 3 blocks
+    (2, 128, 2, 1, 32, 32),       # MQA, small
+    (1, 256, 4, 2, 192, 128),     # latent attention widths, GQA group 2
 ])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("causal", [True, False])
-def test_flash_attention_sweep(rng, b, s, h, kh, d, dtype, causal):
+def test_flash_attention_sweep(rng, b, s, h, kh, d, dv, dtype, causal):
     q = jnp.asarray(rng.standard_normal((b, s, h, d)), dtype)
     k = jnp.asarray(rng.standard_normal((b, s, kh, d)), dtype)
-    v = jnp.asarray(rng.standard_normal((b, s, kh, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, s, kh, dv)), dtype)
     out = flash_attention(
-        q, k, v, causal=causal, block_q=64, block_k=64, interpret=True
+        q, k, v, causal=causal, block_q=128, block_kv=128, interpret=True
     )
     ref = attention_ref(q, k, v, causal=causal)
+    assert out.shape == (b, s, h, dv) and out.dtype == dtype
     np.testing.assert_allclose(
         np.asarray(out, np.float32), np.asarray(ref, np.float32), **tol(dtype)
     )
 
 
-def test_flash_attention_grad_matches_ref(rng):
-    q = jnp.asarray(rng.standard_normal((1, 96, 2, 32)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((1, 96, 2, 32)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((1, 96, 2, 32)), jnp.float32)
+@pytest.mark.parametrize("b,s,h,kh,d,dv", [
+    (1, 128, 2, 2, 32, 32),       # MHA, one block
+    (1, 256, 4, 2, 192, 128),     # latent attention widths, GQA, 2 x 2 blocks
+])
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+def test_flash_attention_grad_matches_ref(rng, b, s, h, kh, d, dv, dtype):
+    """Gradients of q, k and v through the kernel's dq and dkv kernels."""
+    q = jnp.asarray(rng.standard_normal((b, s, h, d)), dtype)
+    k = jnp.asarray(rng.standard_normal((b, s, kh, d)), dtype)
+    v = jnp.asarray(rng.standard_normal((b, s, kh, dv)), dtype)
+    w = jnp.asarray(rng.standard_normal((b, s, h, dv)), jnp.float32)
 
-    g1 = jax.grad(lambda q_: flash_attention(q_, k, v, interpret=True).sum())(q)
-    g2 = jax.grad(lambda q_: attention_ref(q_, k, v).sum())(q)
-    np.testing.assert_allclose(np.asarray(g1), np.asarray(g2), rtol=2e-4, atol=2e-4)
+    def grads(fn):
+        loss = lambda *qkv: jnp.sum(fn(*qkv).astype(jnp.float32) * w)
+        return jax.grad(loss, argnums=(0, 1, 2))(q, k, v)
+
+    got = grads(lambda *qkv: flash_attention(
+        *qkv, block_q=128, block_kv=128, interpret=True))
+    want = grads(attention_ref)
+    rel = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for g1, g2 in zip(got, want):
+        assert g1.dtype == dtype
+        g1, g2 = np.asarray(g1, np.float32), np.asarray(g2, np.float32)
+        np.testing.assert_allclose(g1, g2, rtol=0, atol=rel * np.abs(g2).max())
 
 
 def test_flash_attention_cross_lengths(rng):
-    q = jnp.asarray(rng.standard_normal((1, 64, 4, 64)), jnp.float32)
+    q = jnp.asarray(rng.standard_normal((1, 128, 4, 64)), jnp.float32)
     k = jnp.asarray(rng.standard_normal((1, 256, 4, 64)), jnp.float32)
     v = jnp.asarray(rng.standard_normal((1, 256, 4, 64)), jnp.float32)
     out = flash_attention(
-        q, k, v, causal=False, block_q=64, block_k=128, interpret=True
+        q, k, v, causal=False, block_q=128, block_kv=128, interpret=True
     )
     ref = attention_ref(q, k, v, causal=False)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=2e-5, atol=2e-5)
+
+
+def test_flash_attention_refuses_unaligned_length(rng):
+    q = jnp.zeros((1, 200, 2, 64), jnp.float32)
+    with pytest.raises(ValueError, match="multiple of 128"):
+        flash_attention(q, q, q, interpret=True)
 
 
 # -- rmsnorm ------------------------------------------------------------------

@@ -134,7 +134,8 @@ def test_moonlight_share_train_step_compiles(one_chip, monkeypatch):
     asserted.  The held experts run as the grouped-product kernels
     (``gmm``, and ``tgmm`` for the weights' gradients), not as capacity
     one-hots; the CPU tests run the kernels interpreted, so this test asks
-    for the compiled ones."""
+    for the compiled ones.  On the one-chip mesh, attention at 8k takes the
+    flash kernel: the splash forward, dq and dkv kernels."""
     from repro.models import build_model
     from repro.models import moe
     from repro.models.sharding import make_ctx, use_sharding
@@ -167,16 +168,33 @@ def test_moonlight_share_train_step_compiles(one_chip, monkeypatch):
     assert mem.peak_memory_in_bytes < HBM_BYTES
     text = compiled.as_text()
     assert "%gmm" in text and "%tgmm" in text
+    for phase in ("fwd", "dq", "dkv"):
+        assert f"splash_mha_{phase}" in text
+
+
+def _fwd_bwd(fn):
+    """fn's output and the gradients of q, k and v for that output as the
+    cotangent: the forward and every backward kernel in one program."""
+    def f(q, k, v):
+        out, vjp = jax.vjp(fn, q, k, v)
+        return out, vjp(out)
+    return f
+
+
+def _moonlight_attention(sds):
+    """Latent attention of the train-moonlight-ep8-8k cell: batch 1, seq
+    8192, 16 heads, query/key width 192 (128 + 64 rotary), value 128."""
+    return [sds((1, 8192, 16, 192), jnp.bfloat16),
+            sds((1, 8192, 16, 192), jnp.bfloat16),
+            sds((1, 8192, 16, 128), jnp.bfloat16)]
 
 
 def _flash(sds):
     from repro.kernels import flash_attention
 
     return (
-        lambda q, k, v: flash_attention(q, k, v, causal=True),
-        [sds((1, 2048, 32, 64), jnp.bfloat16),
-         sds((1, 2048, 8, 64), jnp.bfloat16),
-         sds((1, 2048, 8, 64), jnp.bfloat16)],
+        _fwd_bwd(lambda q, k, v: flash_attention(q, k, v, causal=True)),
+        _moonlight_attention(sds),
     )
 
 
@@ -192,9 +210,33 @@ def _rmsnorm(sds):
 # ssd_scan is left out: the TPU lowering has no cumsum (ROADMAP C2)
 @pytest.mark.parametrize("kernel", [_flash, _rmsnorm], ids=["flash", "rmsnorm"])
 def test_pallas_kernel_compiles_to_tpu_custom_call(one_chip, kernel):
-    """Each kernel at granite-3-2b's attention / norm shapes, seq 2048."""
+    """Flash attention forward and backward at the moonlight cell's
+    attention shapes; the norm at granite-3-2b's, seq 2048."""
     rep = NamedSharding(one_chip, P())
     sds = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep)
     fn, args = kernel(sds)
     compiled = jax.jit(fn).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_temporaries_below_blockwise(one_chip):
+    """At the moonlight cell's attention shapes, forward and backward, the
+    blockwise scan (fp32 score tiles of every key block against every query,
+    through HBM) needs more temporary memory than the flash kernel, whose
+    score tiles stay in VMEM."""
+    from repro.kernels import flash_attention
+    from repro.models import layers as L
+
+    cfg = get_config("moonlight-16b-a3b")
+    rep = NamedSharding(one_chip, P())
+    args = _moonlight_attention(
+        lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep))
+
+    def temp(fn):
+        compiled = jax.jit(_fwd_bwd(fn)).lower(*args).compile()
+        return compiled.memory_analysis().temp_size_in_bytes
+
+    flash = temp(lambda q, k, v: flash_attention(q, k, v, causal=True))
+    blockwise = temp(lambda q, k, v: L._sdpa_blockwise(
+        L._group_q(q, k.shape[2]), k, v, cfg, q_offset=0))
+    assert blockwise > 2 * flash
