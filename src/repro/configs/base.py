@@ -140,30 +140,12 @@ class ArchConfig:
     remat_policy: str = "dots"     # none | dots | full   (see repro.train.step)
     grad_accum: int = 1            # microbatch count for train_4k
     optimizer: str = "adamw"       # adamw | adafactor
-    # attention implementation: "auto" picks, from this many KV tokens up,
-    # the flash kernel for causal self-attention on one TPU and blockwise
-    # (online-softmax) elsewhere, plain dense below it (layers.attn_path).
-    attn_impl: str = "auto"
-    attn_block_kv: int = 512
-    flash_threshold: int = 8192
-    # GQA KV replication target: 0 -> repeat KV heads all the way to H
-    # (baseline); N -> repeat only to N heads (e.g. the TP width) and use the
-    # grouped-attention einsum, cutting KV HBM traffic by H/N while keeping
-    # the head dim shardable.  See EXPERIMENTS.md §Perf.
-    gqa_repeat_to: int = 0
-    # KV-cache storage: "bfloat16" (baseline) or "int8" (per-token-per-head
-    # symmetric quantization; halves decode cache reads — §Perf).
-    kv_cache_dtype: str = "bfloat16"
     # per-arch sharding rule overrides (see models/sharding.py), e.g. phi4
     # trades head sharding (24 % 16 != 0) for sequence sharding of attention.
     sharding_overrides: Optional[dict] = None
     # FSDP-style parameter sharding over the data axis (ZeRO-3/"fsdp" in
     # maxtext terms) — required for >=100B configs to fit per-chip HBM.
     fsdp_params: bool = False
-    # logical axes excluded from FSDP (e.g. ("experts",): expert weights are
-    # already model-sharded and regathering all E experts per microbatch when
-    # only top-k are active is pure waste — see EXPERIMENTS.md §Perf/kimi).
-    fsdp_exclude: tuple = ()
     # chunked cross-entropy: max (seq*vocab) elements per device before the
     # loss switches to a seq-chunked logsumexp scan.
     loss_chunk: int = 512

@@ -89,16 +89,6 @@ def _state_axes(opt_name: str, params_axes):
     return ((), params_axes, _opt_state_axes(opt_name, params_axes))
 
 
-def _fsdp_flag(cfg):
-    """Per-leaf FSDP predicate honoring cfg.fsdp_exclude (selective FSDP)."""
-    if not cfg.fsdp_params:
-        return False
-    if not cfg.fsdp_exclude:
-        return True
-    excl = set(cfg.fsdp_exclude)
-    return lambda axes: not ({a for a in axes if a} & excl)
-
-
 def build_cell(arch: str, shape_name: str, multi_pod: bool, cfg=None):
     """Returns (fn, arg_shapes, in_shardings, out_shardings, ctx, meta)."""
     cfg = cfg or get_config(arch)
@@ -122,7 +112,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool, cfg=None):
         # params: TP (+FSDP over data for >=100B configs); optimizer state:
         # always ZeRO-1 sharded over the data axis.
         params_specs = tree_specs(
-            ctx, state_shapes.params, params_axes, zero1=_fsdp_flag(cfg)
+            ctx, state_shapes.params, params_axes, zero1=cfg.fsdp_params
         )
         opt_specs = tree_specs(
             ctx,
@@ -169,7 +159,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool, cfg=None):
     # >=100B-class configs additionally shard serving weights over the data
     # axis (weight-gathered serving) — TP alone leaves 50+ GB per chip.
     params_specs = tree_specs(
-        ctx, params_shapes, params_axes, zero1=_fsdp_flag(cfg)
+        ctx, params_shapes, params_axes, zero1=cfg.fsdp_params
     )
     params_sh = jax.tree_util.tree_map(
         sh, params_specs, is_leaf=lambda x: isinstance(x, P)

@@ -168,7 +168,7 @@ def init_cache(batch: int, max_len: int, cfg: ArchConfig, dtype):
 def cache_axes(cfg: ArchConfig):
     return _prefix_layers(
         {
-            "self": L.kv_cache_axes(cfg),
+            "self": L.KV_CACHE_AXES,
             "cross": {
                 "k": ("batch", "seq", "kv_heads", "head_dim"),
                 "v": ("batch", "seq", "kv_heads", "head_dim"),

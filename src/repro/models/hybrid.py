@@ -270,7 +270,7 @@ def cache_axes(cfg: ArchConfig):
         return _prefix_layers(dict(MB.MAMBA_CACHE_AXES))
     return _prefix_layers(
         {
-            "kv": L.kv_cache_axes(cfg),
+            "kv": L.KV_CACHE_AXES,
             "ssm": _prefix_layers(dict(MB.MAMBA_CACHE_AXES)),
         }
     )

@@ -19,6 +19,11 @@ import jax.numpy as jnp
 from repro.kernels.flash_attention.ops import LANES, flash_attention
 from repro.models.sharding import current_ctx, shard_hint
 
+# From this many keys up, attention leaves the dense path (:func:`attn_path`).
+LONG_KV = 8192
+# Keys per step of the blockwise scan (:func:`_sdpa_blockwise`).
+BLOCK_KV = 512
+
 
 def _init_dense(key, shape, scale_dim, dtype):
     scale = 1.0 / math.sqrt(scale_dim)
@@ -170,23 +175,13 @@ def repeat_kv(kv, num_heads: int):
     return kv.reshape(b, s, num_heads, hd)
 
 
-def _kv_target(cfg, kv_heads: int) -> int:
-    """How many KV heads to materialize: H (baseline full repeat) or the
-    configured gqa_repeat_to (minimal-replication grouped attention)."""
-    h = cfg.num_heads
-    t = cfg.gqa_repeat_to
-    if t and kv_heads <= t <= h and h % t == 0 and t % kv_heads == 0:
-        return t
-    return h
-
-
 def _group_q(q, k_eff: int):
     """(B,S,H,hd) -> (B,S,K_eff,G,hd) with query head h -> kv head h//G."""
     b, s, h, hd = q.shape
     return q.reshape(b, s, k_eff, h // k_eff, hd)
 
 
-def _sdpa_dense(qg, k, v, mask, cfg):
+def _sdpa_dense(qg, k, v, mask):
     """Grouped attention.  qg: (B,Sq,K,G,hd); k,v: (B,Skv,K,hd);
     mask broadcastable to (B,1,1,Sq,Skv).  G=1 == plain MHA."""
     scale = 1.0 / math.sqrt(qg.shape[-1])
@@ -201,15 +196,13 @@ def _sdpa_dense(qg, k, v, mask, cfg):
     )
 
 
-def _sdpa_blockwise(
-    qg, k, v, cfg, *, q_offset: int, kv_valid=None, bidirectional: bool = False
-):
+def _sdpa_blockwise(qg, k, v, *, q_offset: int, bidirectional: bool = False):
     """Grouped online-softmax attention, scanning KV in blocks (jnp flash).
 
     qg: (B,Sq,K,G,hd); k: (B,Skv,K,hd); v: (B,Skv,K,vd).  Memory is
-    O(Sq * block_kv) instead of O(Sq * Skv); the block body is
+    O(Sq * BLOCK_KV) instead of O(Sq * Skv); the block body is
     rematerialized, so the backward pass keeps each block's carry and not
-    its (Sq, block_kv) scores.  Causal masking uses global positions: query i
+    its (Sq, BLOCK_KV) scores.  Causal masking uses global positions: query i
     attends to kv j iff j <= i + q_offset.  It computes every (query, key)
     block pair, the fully masked ones included; on one TPU, causal
     self-attention takes the flash kernel instead (:func:`attn_path`).
@@ -217,7 +210,7 @@ def _sdpa_blockwise(
     scale = 1.0 / math.sqrt(qg.shape[-1])
     b, sq, kh, g, hd = qg.shape
     skv, vd = k.shape[1], v.shape[-1]
-    blk = min(cfg.attn_block_kv, skv)
+    blk = min(BLOCK_KV, skv)
     assert skv % blk == 0, f"kv len {skv} % block {blk} != 0"
     nblk = skv // blk
     kb = jnp.moveaxis(k.reshape(b, nblk, blk, kh, hd), 1, 0)
@@ -240,8 +233,6 @@ def _sdpa_blockwise(
                 kj_pos[None, None, None, None, :]
                 <= qi[None, None, None, :, None]
             )
-        if kv_valid is not None:
-            mask = mask & (kj_pos[None, None, None, None, :] < kv_valid)
         s = jnp.where(mask, s, -jnp.inf)
         m_new = jnp.maximum(m, jnp.max(s, axis=-1))
         # guard fully-masked rows (m_new == -inf): scale of 0 keeps them empty
@@ -269,36 +260,33 @@ def _sdpa_blockwise(
 
 
 def attn_path(
-    cfg, sq: int, skv: int, *, platform: str, devices: int, mask=None,
-    q_offset=0, kv_valid=None, bidirectional: bool = False,
+    sq: int, skv: int, *, platform: str, devices: int, mask=None,
+    q_offset=0, bidirectional: bool = False,
 ) -> str:
-    """Which attention :func:`_sdpa` runs: "flash", "blockwise" or "dense".
+    """Which attention :func:`_sdpa` runs: "flash", "blockwise" or "dense",
+    from the shapes and the placement alone.
 
     "flash" (``repro.kernels.flash_attention``: scores stay in VMEM, fully
     masked blocks are skipped forward and backward) where the input shows it
-    is sound: one TPU device, causal self-attention with no mask, offset or
-    valid length, ``sq == skv >= cfg.flash_threshold`` and a multiple of 128.
-    On a mesh of several devices the blockwise scan stays, since the kernel
-    is not partitioned.  "blockwise" for other long queries without a mask
-    (bidirectional, an offset, a valid length), and "dense" otherwise:
-    decode, masked chunks, short sequences.
+    is sound: one TPU device, causal self-attention with no mask or offset,
+    ``sq == skv >= LONG_KV`` and a multiple of 128.  On a mesh of several
+    devices the blockwise scan stays, since the kernel is not partitioned.
+    "blockwise" (online softmax over ``BLOCK_KV`` keys at a time) for other
+    long queries without a mask (bidirectional, an offset), and "dense"
+    otherwise: decode, masked chunks, short sequences.
     """
-    if cfg.attn_impl == "blockwise":
-        return "blockwise"
-    if cfg.attn_impl != "auto":
-        return "dense"
     causal_self = (
-        mask is None and not bidirectional and kv_valid is None
+        mask is None and not bidirectional
         and isinstance(q_offset, int) and q_offset == 0 and sq == skv
     )
     if (platform == "tpu" and devices == 1 and causal_self
-            and skv >= cfg.flash_threshold and skv % LANES == 0):
+            and skv >= LONG_KV and skv % LANES == 0):
         return "flash"
     # blockwise only pays off for long query blocks (train/prefill): for
     # decode (Sq=1) dense scores are tiny and, crucially, a lax.scan over a
     # sequence-sharded KV cache would force XLA to gather every block on
     # every device, defeating split-KV sharding.
-    if sq >= 256 and skv >= cfg.flash_threshold and mask is None:
+    if sq >= 256 and skv >= LONG_KV and mask is None:
         return "blockwise"
     return "dense"
 
@@ -314,40 +302,28 @@ def placement() -> dict:
     return {"platform": devs.flat[0].platform, "devices": devs.size}
 
 
-def _sdpa(
-    q, k, v, mask, cfg, *, q_offset: int = 0, kv_valid=None, bidirectional=False
-):
+def _sdpa(q, k, v, mask, *, q_offset: int = 0, bidirectional=False):
     """q: (B,Sq,H,hd); k: (B,Skv,K,hd), v: (B,Skv,K,vd) with K the
     *stored* kv-head count.  Returns (B,Sq,H,vd).
 
     Runs the path :func:`attn_path` picks.  The flash kernel reads K/V by
-    KV-head group; the blockwise and dense paths repeat KV to
-    ``_kv_target`` heads (H baseline; the TP width when
-    ``cfg.gqa_repeat_to`` is set) and group the queries over them.
+    KV-head group; the blockwise and dense paths repeat KV to H heads.
     """
-    b, sq, h, hd = q.shape
-    if cfg.attn_impl == "proxy":
-        # measurement stub: zero-traffic attention (same output shape) used
-        # to DIFF the XLA-side attention HBM traffic out of a dry-run so the
-        # Pallas flash kernel's analytic traffic can be substituted
-        # (EXPERIMENTS.md §Perf / qwen1.5-110b prefill)
-        return q * (1.0 / math.sqrt(hd))
+    b, sq, h, _ = q.shape
     skv = k.shape[1]
     path = attn_path(
-        cfg, sq, skv, mask=mask, q_offset=q_offset, kv_valid=kv_valid,
-        bidirectional=bidirectional, **placement(),
+        sq, skv, mask=mask, q_offset=q_offset, bidirectional=bidirectional,
+        **placement(),
     )
     if path == "flash":
         with jax.named_scope("attn.flash"):
             return flash_attention(q, k, v, causal=True)
-    k_eff = _kv_target(cfg, k.shape[2])
-    k = repeat_kv(k, k_eff)
-    v = repeat_kv(v, k_eff)
-    qg = _group_q(q, k_eff)
+    k = repeat_kv(k, h)
+    v = repeat_kv(v, h)
+    qg = _group_q(q, h)
     if path == "blockwise":
         out = _sdpa_blockwise(
-            qg, k, v, cfg, q_offset=q_offset, kv_valid=kv_valid,
-            bidirectional=bidirectional,
+            qg, k, v, q_offset=q_offset, bidirectional=bidirectional
         )
         return out.reshape(b, sq, h, -1)
     if mask is None:
@@ -355,10 +331,8 @@ def _sdpa(
             mask = jnp.ones((1, 1, 1, 1), bool)
         else:
             mask = causal_mask(sq, skv, offset=q_offset)
-            if kv_valid is not None:
-                mask = mask & (jnp.arange(skv)[None, None, None, :] < kv_valid)
     # grouped mask shape: (B, 1[K], 1[G], Sq, Skv)
-    out = _sdpa_dense(qg, k, v, mask[:, :, None], cfg)
+    out = _sdpa_dense(qg, k, v, mask[:, :, None])
     return out.reshape(b, sq, h, -1)
 
 
@@ -384,7 +358,7 @@ def attention(p, x, cfg, *, positions, mask=None, cross_kv=None, bidirectional=F
             q = q + p["bq"].astype(cdt)
         k, v = cross_kv
         bidirectional = True
-    out = _sdpa(q, k, v, mask, cfg, bidirectional=bidirectional)
+    out = _sdpa(q, k, v, mask, bidirectional=bidirectional)
     return jnp.einsum("bqhk,hkd->bqd", out, p["wo"].astype(cdt))
 
 
@@ -406,18 +380,9 @@ def init_kv_cache(batch: int, max_len: int, cfg, dtype):
     """Per-position keys and values; latent attention caches its expanded
     per-head keys (nope + rope wide) and values."""
     K = cfg.num_kv_heads
-    k_shape = (batch, max_len, K, cfg.qk_head_dim)
-    v_shape = (batch, max_len, K, cfg.v_dim)
-    if getattr(cfg, "kv_cache_dtype", "bfloat16") == "int8":
-        return {
-            "k": jnp.zeros(k_shape, jnp.int8),
-            "v": jnp.zeros(v_shape, jnp.int8),
-            "k_scale": jnp.zeros((batch, max_len, K, 1), jnp.bfloat16),
-            "v_scale": jnp.zeros((batch, max_len, K, 1), jnp.bfloat16),
-        }
     return {
-        "k": jnp.zeros(k_shape, dtype),
-        "v": jnp.zeros(v_shape, dtype),
+        "k": jnp.zeros((batch, max_len, K, cfg.qk_head_dim), dtype),
+        "v": jnp.zeros((batch, max_len, K, cfg.v_dim), dtype),
     }
 
 
@@ -427,36 +392,8 @@ KV_CACHE_AXES = {
 }
 
 
-def kv_cache_axes(cfg) -> dict:
-    axes = dict(KV_CACHE_AXES)
-    if getattr(cfg, "kv_cache_dtype", "bfloat16") == "int8":
-        axes["k_scale"] = ("batch", "kv_seq", "kv_heads", None)
-        axes["v_scale"] = ("batch", "kv_seq", "kv_heads", None)
-    return axes
-
-
-def _kv_quantize(t):
-    """(B,S,K,hd) -> (int8 values, (B,S,K,1) bf16 scales)."""
-    amax = jnp.max(jnp.abs(t.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.maximum(amax / 127.0, 1e-8)
-    q = jnp.clip(jnp.round(t.astype(jnp.float32) / scale), -127, 127)
-    return q.astype(jnp.int8), scale.astype(jnp.bfloat16)
-
-
-def _cache_write(cache, k, v, pos: int | jax.Array, cfg, cdt):
+def _cache_write(cache, k, v, pos: int | jax.Array):
     """Write k/v (B,S,K,hd) into the cache at sequence offset ``pos``."""
-    if "k_scale" in cache:
-        qk, sk = _kv_quantize(k)
-        qv, sv = _kv_quantize(v)
-        at = lambda buf, upd: jax.lax.dynamic_update_slice(
-            buf, upd.astype(buf.dtype), (0, pos, 0, 0)
-        )
-        return {
-            "k": at(cache["k"], qk),
-            "v": at(cache["v"], qv),
-            "k_scale": at(cache["k_scale"], sk),
-            "v_scale": at(cache["v_scale"], sv),
-        }
     return {
         "k": jax.lax.dynamic_update_slice(
             cache["k"], k.astype(cache["k"].dtype), (0, pos, 0, 0)
@@ -467,21 +404,12 @@ def _cache_write(cache, k, v, pos: int | jax.Array, cfg, cdt):
     }
 
 
-def _cache_read(cache, cdt):
-    """Dequantized (k, v) in compute dtype."""
-    if "k_scale" in cache:
-        k = cache["k"].astype(cdt) * cache["k_scale"].astype(cdt)
-        v = cache["v"].astype(cdt) * cache["v_scale"].astype(cdt)
-        return k, v
-    return cache["k"].astype(cdt), cache["v"].astype(cdt)
-
-
 def attention_prefill(p, x, cfg, *, positions, cache):
     """Compute full attention AND write k/v into the cache at [0, S)."""
     q, k, v = _project_qkv(p, x, cfg, positions)
-    out = _sdpa(q, k, v, None, cfg)
+    out = _sdpa(q, k, v, None)
     cdt = jnp.dtype(cfg.compute_dtype)
-    new_cache = _cache_write(cache, k, v, 0, cfg, cdt)
+    new_cache = _cache_write(cache, k, v, 0)
     return jnp.einsum("bqhk,hkd->bqd", out, p["wo"].astype(cdt)), new_cache
 
 
@@ -495,13 +423,13 @@ def attention_decode(p, x, cfg, *, cache, cache_len):
     positions = jnp.full((b, 1), cache_len, dtype=jnp.int32)
     cdt = jnp.dtype(cfg.compute_dtype)
     q, k, v = _project_qkv(p, x, cfg, positions)
-    new_cache = _cache_write(cache, k, v, cache_len, cfg, cdt)
+    new_cache = _cache_write(cache, k, v, cache_len)
     new_cache = {
-        kk: shard_hint(vv, kv_cache_axes(cfg)[kk], f"cache_{kk}")
+        kk: shard_hint(vv, KV_CACHE_AXES[kk], f"cache_{kk}")
         for kk, vv in new_cache.items()
     }
-    ck, cv = _cache_read(new_cache, cdt)
-    out = _sdpa(q, ck, cv, None, cfg, q_offset=cache_len)
+    ck, cv = new_cache["k"].astype(cdt), new_cache["v"].astype(cdt)
+    out = _sdpa(q, ck, cv, None, q_offset=cache_len)
     y = jnp.einsum("bqhk,hkd->bqd", out, p["wo"].astype(cdt))
     return y, new_cache
 

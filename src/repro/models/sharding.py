@@ -14,9 +14,6 @@ picks the first candidate that
 falling back to replication otherwise.  Every fallback is recorded so the
 dry-run can report exactly which tensors lost which sharding (e.g. the
 24-head phi4 attention on a 16-way ``model`` axis).
-
-Rule tables are plain data — per-cell overrides are how the §Perf hillclimb
-changes sharding strategies without touching model code.
 """
 from __future__ import annotations
 
@@ -250,19 +247,18 @@ def shard_hint(x: jax.Array, axes: Axes, name: str = "act"):
 # ---------------------------------------------------------------------------
 
 
-def tree_specs(ctx: ShardingCtx, shapes, axes_tree, zero1=False):
+def tree_specs(ctx: ShardingCtx, shapes, axes_tree, zero1: bool = False):
     """Map a (shapes, logical-axes) tree pair to PartitionSpecs.
 
     ``shapes`` is any pytree of objects with ``.shape`` (arrays or
     ShapeDtypeStructs); ``axes_tree`` mirrors it with ``Axes`` tuples.
-    ``zero1`` may be a bool or a per-leaf predicate ``axes -> bool``
-    (selective FSDP, e.g. excluding expert weights).
+    ``zero1`` stacks data-axis sharding on every leaf
+    (:meth:`ShardingCtx.zero_spec_for`).
     """
 
     def one(path, leaf, axes):
         name = "/".join(str(getattr(k, "key", k)) for k in path)
-        z = zero1(axes) if callable(zero1) else zero1
-        if z:
+        if zero1:
             return ctx.zero_spec_for(axes, leaf.shape, name)
         return ctx.spec_for(axes, leaf.shape, name)
 

@@ -196,7 +196,7 @@ def flash_layers(params, cfg: ArchConfig, seq: int):
     """How many layers' attention takes the flash kernel at this sequence
     length: every layer's self-attention has the same shapes, so all or
     none (``L.attn_path``)."""
-    path = L.attn_path(cfg, seq, seq, **L.placement())
+    path = L.attn_path(seq, seq, **L.placement())
     layers = sum(jax.tree_util.tree_leaves(b)[0].shape[0] for b in stacks(params))
     return jnp.asarray(layers if path == "flash" else 0, jnp.int32)
 
@@ -236,7 +236,7 @@ def init_cache(batch: int, max_len: int, cfg: ArchConfig, dtype):
 
 
 def cache_axes(cfg: ArchConfig):
-    return _prefix_layers(L.kv_cache_axes(cfg))
+    return _prefix_layers(L.KV_CACHE_AXES)
 
 
 def _scan_cached(body, h, params, cache):

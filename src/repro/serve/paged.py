@@ -31,10 +31,7 @@ softmax sums, so the padded gathered view computes the same numbers as the
 reference's contiguous cache (asserted token-for-token in
 tests/test_serve_engine.py).
 
-The pool always stores ``cfg.compute_dtype`` (the int8-KV path of
-``layers.init_kv_cache`` quantizes per-position tensors, which a scatter
-write would re-quantize per block — an engine-level policy decision out of
-scope here).
+The pool stores ``cfg.compute_dtype``.
 """
 from __future__ import annotations
 
@@ -105,7 +102,7 @@ def _paged_attention(attn_p, h, cfg, pool_k, pool_v, *,
     kv_shape = (b, view) + khd
     k_view = new_k[tables].reshape(kv_shape)
     v_view = new_v[tables].reshape(kv_shape)
-    out = L._sdpa(q, k_view, v_view, mask, cfg)
+    out = L._sdpa(q, k_view, v_view, mask)
     y = jnp.einsum("bqhk,hkd->bqd", out, attn_p["wo"].astype(cdt))
     return y, new_k, new_v
 
@@ -183,7 +180,7 @@ def _decode_attention(attn_p, h, cfg, pool_k, pool_v, layer, *,
     kv_shape = (s, tables.shape[1] * pool_k.shape[2]) + k.shape[2:]
     k_view = pool_k[layer, tables].reshape(kv_shape)
     v_view = pool_v[layer, tables].reshape(kv_shape)
-    out = L._sdpa_dense(L._group_q(q, kv_heads), k_view, v_view, mask, cfg)
+    out = L._sdpa_dense(L._group_q(q, kv_heads), k_view, v_view, mask)
     y = jnp.einsum(
         "bqhk,hkd->bqd", out.reshape(q.shape), attn_p["wo"].astype(cdt)
     )

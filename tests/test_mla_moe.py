@@ -58,11 +58,13 @@ def _mla_ref(p, x, cfg):
 
 
 @pytest.mark.parametrize("impl", ["dense", "blockwise"])
-def test_mla_attention_matches_reference(impl):
+def test_mla_attention_matches_reference(impl, monkeypatch):
     """Both score paths: one softmax over the causal mask, and the online
-    softmax over key blocks (query/key width 24 against value width 16)."""
-    cfg = _mla_cfg(attn_impl="auto" if impl == "dense" else "blockwise",
-                   attn_block_kv=16)
+    softmax over key blocks of 16 (query/key width 24 against value width
+    16)."""
+    cfg = _mla_cfg()
+    monkeypatch.setattr(L, "BLOCK_KV", 16)
+    monkeypatch.setattr(L, "attn_path", lambda *a, **kw: impl)
     p, _ = L.init_attention(jax.random.PRNGKey(3), cfg)
     x = jax.random.normal(jax.random.PRNGKey(4), (2, 64, cfg.d_model), jnp.float32)
     pos = jnp.broadcast_to(jnp.arange(64, dtype=jnp.int32), (2, 64))

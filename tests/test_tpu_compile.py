@@ -227,7 +227,6 @@ def test_flash_attention_temporaries_below_blockwise(one_chip):
     from repro.kernels import flash_attention
     from repro.models import layers as L
 
-    cfg = get_config("moonlight-16b-a3b")
     rep = NamedSharding(one_chip, P())
     args = _moonlight_attention(
         lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=rep))
@@ -238,5 +237,5 @@ def test_flash_attention_temporaries_below_blockwise(one_chip):
 
     flash = temp(lambda q, k, v: flash_attention(q, k, v, causal=True))
     blockwise = temp(lambda q, k, v: L._sdpa_blockwise(
-        L._group_q(q, k.shape[2]), k, v, cfg, q_offset=0))
+        L._group_q(q, k.shape[2]), k, v, q_offset=0))
     assert blockwise > 2 * flash
